@@ -173,9 +173,13 @@ for shape in SHAPES:
 
 def _forced_device_child(src: str, timeout: int):
     """Run a bench child under XLA_FLAGS forced host devices (the flag
-    must precede jax backend init, hence the subprocess)."""
+    must precede jax backend init, hence the subprocess). The child
+    measures the CPU plane by design, so it is held to the CPU: on a TPU
+    host the parent holds the chip, and a child reaching for it would
+    fail or hang."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count="
                           f"{_PLANE_DEVICES}")

@@ -10,6 +10,9 @@ roofline). Prints ``name,us_per_call,derived`` CSV rows.
 ``--json OUT`` additionally writes a structured ``BENCH_<timestamp>.json``
 perf record (rows + per-bench wall time + environment) next to the
 unchanged CSV stdout; OUT may be a directory or an explicit .json path.
+
+Every row is printed even when a bench fails, but the exit code is 1 if
+any bench raised or printed an ``ERROR:`` row.
 """
 from __future__ import annotations
 
@@ -18,6 +21,8 @@ import json
 import os
 import sys
 import time
+
+from repro.utils.cache import use_compile_cache
 
 BENCHES = {
     "table1": "benchmarks.bench_table1_gaussian",
@@ -81,8 +86,10 @@ def main() -> None:
         sys.exit(2)
 
     import importlib
+
+    use_compile_cache()
     t_start = time.time()
-    records, durations = [], {}
+    records, durations, failed = [], {}, []
     print("name,us_per_call,derived")
     for key in keys:
         mod = importlib.import_module(BENCHES[key])
@@ -94,6 +101,9 @@ def main() -> None:
         for r in rows:
             print(r)
             records.append(_parse_row(key, r))
+        if any(rec["derived"].startswith("ERROR:")
+               for rec in records if rec["bench"] == key):
+            failed.append(key)
         durations[key] = round(time.time() - t0, 2)
         print(f"# {key} done in {durations[key]:.1f}s", file=sys.stderr)
 
@@ -122,6 +132,10 @@ def main() -> None:
         with open(path, "w") as f:
             json.dump(record, f, indent=2)
         print(f"# perf record -> {path}", file=sys.stderr)
+    if failed:
+        print(f"error: bench(es) failed: {', '.join(failed)}",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

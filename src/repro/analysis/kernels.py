@@ -15,9 +15,14 @@ evaluates those plans across the REGISTERED bucket ladder shapes (the
     grid PARTITIONS (block extent < array extent) must tile cleanly:
     the minor (lane) axis in multiples of 128, the second-minor
     (sublane) axis in multiples of 8 for 4-byte / 16 for 2-byte
-    elements. Single-row (extent-1) sublane windows are exempt — they
-    are the scalar-prefetch DMA gather granule, not a partial-tile
-    relayout. Unpartitioned dims only pad, never relayout.
+    elements. The TPU compiler refuses anything else, a single-row
+    window included: a one-row gather travels as a (rows, 1, d) array,
+    whose unit sublane axis the block spans whole. Unpartitioned dims
+    only pad, never relayout.
+  * ``short-1d-block`` — a 1-D block that partitions its array must
+    cover whole XLA layout tiles (1024 elements): XLA lays a 1-D array
+    out in T(1024) tiles and the compiler refuses a kernel whose block
+    implies a different tile. Per-row vectors travel as (1, n) rows.
   * ``bf16-accum`` — sub-4-byte storage must declare f32 accumulation
     (the ``preferred_element_type`` contract of every matmul kernel
     here); bf16-accumulating reductions drift from the f32 oracles.
@@ -32,6 +37,7 @@ PASS = "kernels"
 
 _ITEMSIZE = {"f32": 4, "i32": 4, "bf16": 2, "f16": 2, "i8": 1}
 _LANE = 128
+_TILE_1D = 1024   # XLA's layout tile of a 1-D array on the TPU
 
 
 def _sublane(dtype: str) -> int:
@@ -73,9 +79,18 @@ def check_plan(plan: dict, hw: dict, shape_tag: str = "") -> List[Finding]:
             f"{plan['grid']}): shrink the block tiles"))
 
     for b in plan["blocks"]:
-        if b["kind"] == "scalar" or len(b["shape"]) < 2:
+        if b["kind"] == "scalar":
             continue
         shape, arr = b["shape"], b["array_shape"]
+        if len(shape) == 1:
+            if int(shape[0]) < int(arr[0]) and int(shape[0]) % _TILE_1D:
+                findings.append(Finding(
+                    PASS, "short-1d-block", where,
+                    f"1-D block {b['name']}{tuple(shape)} partitions a "
+                    f"{tuple(arr)} array in pieces that are not whole "
+                    f"{_TILE_1D}-element XLA layout tiles: the TPU "
+                    f"compiler refuses it; use a (1, n) row"))
+            continue
         lane, sub = int(shape[-1]), int(shape[-2])
         lane_part = lane < int(arr[-1])
         sub_part = sub < int(arr[-2])
@@ -83,15 +98,15 @@ def check_plan(plan: dict, hw: dict, shape_tag: str = "") -> List[Finding]:
             findings.append(Finding(
                 PASS, "lane-misaligned", where,
                 f"block {b['name']}{shape} partitions the lane axis at "
-                f"{lane}, not a multiple of {_LANE}: partial lane tiles "
-                f"force a relayout copy per grid step"))
+                f"{lane}, not a multiple of {_LANE}: the TPU compiler "
+                f"refuses partial lane tiles"))
         sl = _sublane(b["dtype"])
-        if sub_part and sub != 1 and sub % sl:
+        if sub_part and sub % sl:
             findings.append(Finding(
                 PASS, "sublane-misaligned", where,
                 f"block {b['name']}{shape} partitions the sublane axis "
                 f"at {sub}, not a multiple of {sl} for {b['dtype']}: "
-                f"partial sublane tiles force a relayout copy"))
+                f"the TPU compiler refuses partial sublane tiles"))
 
     if _ITEMSIZE[plan["storage"]] < 4 and plan["accum"] != "f32":
         findings.append(Finding(

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
+from repro.kernels.ref import HIGHEST
 
 
 def assign_points(x: jax.Array, centers: jax.Array,
@@ -186,7 +187,8 @@ def maxmin_grow(pf: jax.Array, valid: jax.Array, chosen: jax.Array,
         cand = reducer.argmax(mind2)
         chosen = jnp.where(grow, chosen.at[t].set(cand), chosen)
         c = reducer.fetch_row(pf, cand)
-        nd = jnp.maximum(p2 - 2.0 * (pf @ c) + jnp.sum(c * c), 0.0)
+        nd = jnp.maximum(p2 - 2.0 * jnp.matmul(pf, c, precision=HIGHEST)
+                         + jnp.sum(c * c), 0.0)
         nd = jnp.where(valid, nd, -jnp.inf)
         mind2 = jnp.where(grow, jnp.minimum(mind2, nd), mind2)
         return chosen, mind2

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.core import lloyd as L
 from repro.kernels import ops
+from repro.kernels.ref import HIGHEST
 
 
 class KFedAggregate(NamedTuple):
@@ -227,7 +228,8 @@ def aggregate_sharded(centers_loc, mask_loc, kz_all, k, axes, base, *,
     sel = ((slot_of[:, None] == jnp.arange(k, dtype=jnp.int32)[None, :])
            & init_loc[:, None]).astype(jnp.float32)       # (m_loc, k)
     M0 = jax.lax.dot_general(sel, jnp.where(init_loc[:, None], pf, 0.0),
-                             (((0,), (0,)), ((), ())))    # (k, d)
+                             (((0,), (0,)), ((), ())),
+                             precision=HIGHEST)           # (k, d)
     M0 = red.psum(M0)
 
     d2 = ops.pairwise_sq_dists(pf, M0)                    # (m_loc, k)
@@ -404,7 +406,8 @@ def center_mass(agg: KFedAggregate, mask: jax.Array,
     # bitwise (§15 float-scatter-add rule).
     oh = (lbl[:, None] == jnp.arange(k, dtype=jnp.int32)[None, :]
           ).astype(jnp.float32)                           # (m, k)
-    return jax.lax.dot_general(w, oh, (((0,), (0,)), ((), ())))
+    return jax.lax.dot_general(w, oh, (((0,), (0,)), ((), ())),
+                               precision=HIGHEST)
 
 
 def split_retire(flat: jax.Array, fm: jax.Array, agg: KFedAggregate,

@@ -25,9 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _round_up(v: int, m: int) -> int:
-    return ((v + m - 1) // m) * m
+from repro.kernels.layout import round_up
 
 
 def _dispatch_kernel(src_ref, valid_ref, x_ref, out_ref):
@@ -58,8 +56,8 @@ def _moe_dispatch(x: jax.Array, src: jax.Array, valid: jax.Array,
                   *, bd: int, interpret: bool):
     T, d = x.shape
     S = src.shape[0]
-    dp = _round_up(d, bd)
-    xp = jnp.zeros((T, dp), x.dtype).at[:, :d].set(x)
+    dp = round_up(d, bd)
+    xp = jnp.zeros((T, 1, dp), x.dtype).at[:, 0, :d].set(x)
     src_c = jnp.clip(src, 0, T - 1).astype(jnp.int32)
     val_i = valid.astype(jnp.int32)
 
@@ -72,34 +70,37 @@ def _moe_dispatch(x: jax.Array, src: jax.Array, valid: jax.Array,
             in_specs=[
                 # one source row per grid step, chosen by the prefetched
                 # routing index — the DMA gather
-                pl.BlockSpec((1, bd), lambda s, j, src, val: (src[s], j)),
+                pl.BlockSpec((None, 1, bd),
+                             lambda s, j, src, val: (src[s], 0, j)),
             ],
-            out_specs=pl.BlockSpec((1, bd), lambda s, j, src, val: (s, j)),
+            out_specs=pl.BlockSpec((None, 1, bd),
+                                   lambda s, j, src, val: (s, 0, j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((S, dp), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, 1, dp), x.dtype),
         interpret=interpret,
     )(src_c, val_i, xp)
-    return out[:, :d]
+    return out[:, 0, :d]
 
 
 def dispatch_block_plan(T: int, d: int, S: int, *, bd: int = 512,
                         dtype: str = "f32") -> dict:
     """Static BlockSpec/grid metadata of :func:`moe_dispatch` for the
-    §15 kernel checker. The (1, bd) row blocks are the scalar-prefetch
-    DMA gather granule: a 1-row sublane window is the intended stream
-    shape here, not a partial-tile relayout. Routing indices live in
-    SMEM (kind="scalar")."""
+    §15 kernel checker. One row per grid step is the scalar-prefetch DMA
+    gather granule, so rows travel as a (rows, 1, d) array: the block's
+    last two dims (1, bd) then span the array's whole unit sublane axis,
+    which the TPU tiling rule admits. Routing indices live in SMEM
+    (kind="scalar")."""
     store = "f32" if dtype == "f32" else "bf16"
-    dp = _round_up(d, bd)
+    dp = round_up(d, bd)
     blk = [
         dict(name="src", shape=(S,), dtype="i32", kind="scalar",
              resident=True, array_shape=(S,)),
         dict(name="valid", shape=(S,), dtype="i32", kind="scalar",
              resident=True, array_shape=(S,)),
-        dict(name="x", shape=(1, bd), dtype=store, kind="in",
-             resident=False, array_shape=(T, dp)),
-        dict(name="queues", shape=(1, bd), dtype=store, kind="out",
-             resident=False, array_shape=(S, dp)),
+        dict(name="x", shape=(1, 1, bd), dtype=store, kind="in",
+             resident=False, array_shape=(T, 1, dp)),
+        dict(name="queues", shape=(1, 1, bd), dtype=store, kind="out",
+             resident=False, array_shape=(S, 1, dp)),
     ]
     return dict(kernel="moe_dispatch", grid=(S, dp // bd), storage=store,
                 accum=store, blocks=blk)
@@ -111,16 +112,16 @@ def combine_block_plan(S: int, d: int, T: int, *, top_k: int = 2,
     §15 kernel checker — the gather-and-weighted-sum sibling of
     :func:`dispatch_block_plan`, always f32-accumulating."""
     store = "f32" if dtype == "f32" else "bf16"
-    dp = _round_up(d, bd)
+    dp = round_up(d, bd)
     blk = [
         dict(name="slot", shape=(T * top_k,), dtype="i32", kind="scalar",
              resident=True, array_shape=(T * top_k,)),
         dict(name="gates", shape=(T * top_k,), dtype="f32",
              kind="scalar", resident=True, array_shape=(T * top_k,)),
-        dict(name="ybuf", shape=(1, bd), dtype=store, kind="in",
-             resident=False, array_shape=(S, dp)),
-        dict(name="out", shape=(1, bd), dtype="f32", kind="out",
-             resident=False, array_shape=(T, dp)),
+        dict(name="ybuf", shape=(1, 1, bd), dtype=store, kind="in",
+             resident=False, array_shape=(S, 1, dp)),
+        dict(name="out", shape=(1, 1, bd), dtype="f32", kind="out",
+             resident=False, array_shape=(T, 1, dp)),
     ]
     return dict(kernel="moe_combine", grid=(T, top_k, dp // bd),
                 storage=store, accum="f32", blocks=blk)
@@ -148,8 +149,8 @@ def _moe_combine(ybuf: jax.Array, slot: jax.Array, gates: jax.Array,
     S, d = ybuf.shape
     N = slot.shape[0]
     T = N // top_k
-    dp = _round_up(d, bd)
-    yp = jnp.zeros((S, dp), ybuf.dtype).at[:, :d].set(ybuf)
+    dp = round_up(d, bd)
+    yp = jnp.zeros((S, 1, dp), ybuf.dtype).at[:, 0, :d].set(ybuf)
 
     def kernel(slot_ref, gate_ref, y_ref, out_ref):
         t = pl.program_id(0)
@@ -169,14 +170,14 @@ def _moe_combine(ybuf: jax.Array, slot: jax.Array, gates: jax.Array,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, bd),
+                pl.BlockSpec((None, 1, bd),
                              lambda t, j, b, slot, gate:
-                             (slot[t * top_k + j], b)),
+                             (slot[t * top_k + j], 0, b)),
             ],
-            out_specs=pl.BlockSpec((1, bd),
-                                   lambda t, j, b, slot, gate: (t, b)),
+            out_specs=pl.BlockSpec((None, 1, bd),
+                                   lambda t, j, b, slot, gate: (t, 0, b)),
         ),
-        out_shape=jax.ShapeDtypeStruct((T, dp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((T, 1, dp), jnp.float32),
         interpret=interpret,
     )(slot.astype(jnp.int32), gates.astype(jnp.float32), yp)
-    return out[:, :d]
+    return out[:, 0, :d]

@@ -3,9 +3,12 @@
 The framework's numerical code calls these entry points; the backend is
 selected globally (``set_backend``) or per-call. Interpret mode is
 auto-detected from the platform: on TPU the kernels run compiled, on any
-other backend (e.g. this CPU container) they run in interpret mode (the
-kernel body executes in Python for correctness validation). Override
-with ``REPRO_KERNEL_INTERPRET=0|1`` or ``set_backend(..., interpret=)``.
+other backend (e.g. the CPU) they run in interpret mode (the kernel body
+executes in Python for correctness validation). Off the TPU,
+``REPRO_KERNEL_INTERPRET=0|1`` or ``set_backend(..., interpret=)``
+override it; on a TPU an interpret request is refused with
+:class:`InterpretOnTPUError`, so a run on the chip never silently
+measures the interpreter.
 """
 from __future__ import annotations
 
@@ -27,10 +30,27 @@ _STATE = {
 }
 
 
+class InterpretOnTPUError(RuntimeError):
+    """Pallas interpret mode was requested on a TPU backend. The kernels
+    compile there; interpreting them would time the interpreter, not
+    the chip."""
+
+
+def _refuse_on_tpu(interpret: bool, source: str) -> bool:
+    if interpret and jax.default_backend() == "tpu":
+        raise InterpretOnTPUError(
+            f"{source} asks for Pallas interpret mode on a TPU backend; "
+            f"the kernels run compiled there (unset it, or pass "
+            f"interpret=None/False)")
+    return interpret
+
+
 def _auto_interpret() -> bool:
     env = os.environ.get("REPRO_KERNEL_INTERPRET")
     if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "off")
+        return _refuse_on_tpu(
+            env.strip().lower() not in ("0", "false", "no", "off"),
+            f"REPRO_KERNEL_INTERPRET={env}")
     # Compiled Pallas only on TPU; interpret everywhere else. Deferred to
     # first kernel call so importing this module never initializes a
     # backend.
@@ -47,17 +67,22 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     """Per-call override -> resolved interpret flag. Kernel modules call
     this so a direct kernel invocation (bypassing the dispatchers below)
     still gets the platform auto-detection instead of a hardcoded
-    default."""
-    return _interpret() if interpret is None else interpret
+    default; an explicit ``True`` on a TPU is refused."""
+    if interpret is None:
+        return _interpret()
+    return _refuse_on_tpu(interpret, "interpret=True")
 
 
 def set_backend(impl: str, interpret: Optional[bool] = None,
                 chunk_rows: Optional[int] = None) -> None:
     """Select the kernel implementation. ``interpret=None`` re-enables
-    platform auto-detection (compiled on TPU, interpret elsewhere).
+    platform auto-detection (compiled on TPU, interpret elsewhere);
+    ``interpret=True`` on a TPU raises :class:`InterpretOnTPUError`.
     ``chunk_rows`` sets the auto-chunking threshold of
     :func:`assign_argmin` (0 disables)."""
     assert impl in ("ref", "pallas"), impl
+    if interpret is not None:
+        _refuse_on_tpu(interpret, "set_backend(interpret=True)")
     _STATE["impl"] = impl
     _STATE["interpret"] = interpret
     if chunk_rows is not None:

@@ -6,16 +6,17 @@ Algorithm 1 and the one-round server Lloyd of k-FED) is matmul-shaped:
     d(i, r) = ||x_i||^2 - 2 x_i . c_r + ||c_r||^2
 
 We tile (n, d) into (bn, bd) VMEM blocks and the center axis into bk
-blocks, drive the -2 x @ c^T term through the MXU (128-aligned tiles),
-accumulate partial dot products over d-blocks in a (bn, bk) VMEM scratch
-accumulator, and fuse the argmin so the (n, k) distance matrix never
-round-trips to HBM. The per-point running (idx, val) best lives in the
-output block (resident across the k/d grid axes), so VMEM usage is fixed
-at O(bn * (bd + bk)) regardless of k — large-k center sets (the induced
-labeling of a production round with thousands of retained centers)
-stream through in tiles instead of materializing one (bn, k) scratch.
-Outputs are the assignment indices and the min squared distance per
-point; ties resolve to the smallest center index (first occurrence),
+blocks, drive the -2 c @ x^T term through the MXU (128-aligned tiles),
+accumulate partial dot products over d-blocks in a (bk, bn) VMEM scratch
+accumulator (centers on sublanes, points on lanes), and fuse the argmin
+so the (n, k) distance matrix never round-trips to HBM. The per-point
+running (idx, val) best lives in the output block (resident across the
+k/d grid axes), so VMEM usage is fixed at O(bn * (bd + bk)) regardless
+of k — large-k center sets (the induced labeling of a production round
+with thousands of retained centers) stream through in tiles instead of
+materializing one (k, bn) scratch. Outputs are the assignment indices
+and the min squared distance per point, written as lane-dense (1, n)
+rows; ties resolve to the smallest center index (first occurrence),
 matching ``jnp.argmin``.
 """
 from __future__ import annotations
@@ -27,18 +28,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.layout import (LANE, first_min, precision, round_up,
+                                  row)
 from repro.kernels.ref import MASKED_DIST
 
 
-def _round_up(v: int, m: int) -> int:
-    return ((v + m - 1) // m) * m
-
-
 def _kernel(x_ref, c_ref, cn_ref, idx_ref, val_ref, acc_ref, xn_ref):
+    # Centers on sublanes, points on lanes: the distance block is
+    # (bk, bn) and every per-point vector a lane-dense (1, bn) row.
     kb = pl.program_id(1)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
-    bk = acc_ref.shape[1]
+    bk = acc_ref.shape[0]
 
     @pl.when((kb == 0) & (j == 0))
     def _init_best():
@@ -52,27 +53,38 @@ def _kernel(x_ref, c_ref, cn_ref, idx_ref, val_ref, acc_ref, xn_ref):
 
     x = x_ref[...].astype(jnp.float32)
     c = c_ref[...].astype(jnp.float32)
-    # -2 * x @ c.T on the MXU, accumulated over d-blocks.
+    # -2 * c @ x.T on the MXU, accumulated over d-blocks.
     acc_ref[...] += -2.0 * jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        c, x, (((1,), (1,)), ((), ())), precision=precision(jnp.float32),
+        preferred_element_type=jnp.float32)
 
     # ||x||^2 depends only on the row block: accumulate it on the first
     # k-block pass and reuse the scratch for the rest.
     @pl.when(kb == 0)
     def _xnorm():
-        xn_ref[...] += jnp.sum(x * x, axis=1)
+        xn_ref[...] += row(jnp.sum(x * x, axis=1, keepdims=True))
 
     @pl.when(j == nj - 1)
     def _merge():
-        d = acc_ref[...] + cn_ref[...][None, :] + xn_ref[...][:, None]
-        d = jnp.maximum(d, 0.0)
-        bidx = jnp.argmin(d, axis=1).astype(jnp.int32)
-        bval = jnp.min(d, axis=1)
+        d = jnp.maximum(acc_ref[...] + cn_ref[...] + xn_ref[...], 0.0)
+        bval, bidx = first_min(d, 0)
         # Strict < keeps the earlier k-block on ties; within a block
-        # argmin picks the first — together: smallest global index.
+        # first_min picks the first — together: smallest global index.
         better = bval < val_ref[...]
         idx_ref[...] = jnp.where(better, kb * bk + bidx, idx_ref[...])
         val_ref[...] = jnp.where(better, bval, val_ref[...])
+
+
+def _tiles(n: int, d: int, k: int, bn: int, bd: int, bk: int):
+    """The dispatch's tile arithmetic, shared with :func:`block_plan`.
+    ``bn`` rounds up to whole lane tiles (the (1, bn) output rows);
+    ``bd`` and ``bk`` shrink to the data, 128-aligned, so a narrow
+    feature dim or a small center set never pads out to a default-width
+    tile (single-tile reductions are unchanged bitwise)."""
+    bn = round_up(bn, LANE)
+    bd = min(bd, round_up(d, LANE))
+    bk = min(round_up(bk, LANE), round_up(k, LANE))
+    return bn, bd, round_up(d, bd), bk, round_up(round_up(k, LANE), bk)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "bk", "interpret"))
@@ -80,46 +92,41 @@ def _pairwise_argmin(x, c, c_mask, *, bn: int, bd: int, bk: int,
                      interpret: bool):
     n, d = x.shape
     k = c.shape[0]
-    # Shrink the d-tile to the data (128-aligned) so a narrow feature
-    # dim never pads x out to a full default-width tile. Single-tile
-    # reductions are unchanged bitwise (only the zero tail shrinks).
-    bd = min(bd, _round_up(d, 128))
-    dp = _round_up(d, bd)
-    bk = min(_round_up(bk, 128), _round_up(k, 128))
-    kp = _round_up(_round_up(k, 128), bk)
+    bn, bd, dp, bk, kp = _tiles(n, d, k, bn, bd, bk)
 
     cp = jnp.zeros((kp, dp), c.dtype).at[:k, :d].set(c)
-    cn = jnp.sum(cp.astype(jnp.float32) ** 2, axis=1)
+    cn = jnp.sum(cp.astype(jnp.float32) ** 2, axis=1, keepdims=True)
     valid = jnp.arange(kp) < k
     if c_mask is not None:
         valid = valid & jnp.pad(c_mask, (0, kp - k), constant_values=False)
-    cn = jnp.where(valid, cn, MASKED_DIST)
+    cn = jnp.where(valid[:, None], cn, MASKED_DIST)              # (kp, 1)
 
     def call(xp):
         np_ = xp.shape[0]
         grid = (np_ // bn, kp // bk, dp // bd)  # d innermost: acc stays hot
-        return pl.pallas_call(
+        idx, val = pl.pallas_call(
             _kernel,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((bn, bd), lambda i, kb, j: (i, j)),  # x tile
                 pl.BlockSpec((bk, bd), lambda i, kb, j: (kb, j)),  # centers
-                pl.BlockSpec((bk,), lambda i, kb, j: (kb,)),  # masked norms
+                pl.BlockSpec((bk, 1), lambda i, kb, j: (kb, 0)),  # norms
             ],
             out_specs=[
-                pl.BlockSpec((bn,), lambda i, kb, j: (i,)),
-                pl.BlockSpec((bn,), lambda i, kb, j: (i,)),
+                pl.BlockSpec((1, bn), lambda i, kb, j: (0, i)),
+                pl.BlockSpec((1, bn), lambda i, kb, j: (0, i)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((np_,), jnp.int32),
-                jax.ShapeDtypeStruct((np_,), jnp.float32),
+                jax.ShapeDtypeStruct((1, np_), jnp.int32),
+                jax.ShapeDtypeStruct((1, np_), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((bn, bk), jnp.float32),
-                pltpu.VMEM((bn,), jnp.float32),
+                pltpu.VMEM((bk, bn), jnp.float32),
+                pltpu.VMEM((1, bn), jnp.float32),
             ],
             interpret=interpret,
         )(xp, cp, cn)
+        return idx[0], val[0]
 
     def pad_d(xs):
         if d == dp:
@@ -148,30 +155,27 @@ def _pairwise_argmin(x, c, c_mask, *, bn: int, bd: int, bk: int,
 def block_plan(n: int, d: int, k: int, *, bn: int = 128, bd: int = 512,
                bk: int = 512, dtype: str = "f32") -> dict:
     """Static BlockSpec/grid metadata of :func:`_pairwise_argmin` for
-    the §15 kernel checker — the same tile-shrinking arithmetic as the
-    dispatch above, including the (bn, bk) accumulator and (bn,) x-norm
-    VMEM scratch that bound the footprint independently of k."""
+    the §15 kernel checker — the same tile arithmetic as the dispatch
+    above, including the (bk, bn) accumulator and (1, bn) x-norm VMEM
+    scratch that bound the footprint independently of k."""
     store = "f32" if dtype == "f32" else "bf16"
-    bd = min(bd, _round_up(d, 128))
-    dp = _round_up(d, bd)
-    bk = min(_round_up(bk, 128), _round_up(k, 128))
-    kp = _round_up(_round_up(k, 128), bk)
-    np_ = _round_up(n, bn)
+    bn, bd, dp, bk, kp = _tiles(n, d, k, bn, bd, bk)
+    np_ = round_up(n, bn)
     blk = [
         dict(name="x", shape=(bn, bd), dtype=store, kind="in",
              resident=False, array_shape=(np_, dp)),
         dict(name="centers", shape=(bk, bd), dtype=store, kind="in",
              resident=False, array_shape=(kp, dp)),
-        dict(name="center_norms", shape=(bk,), dtype="f32", kind="in",
-             resident=False, array_shape=(kp,)),
-        dict(name="idx", shape=(bn,), dtype="i32", kind="out",
-             resident=False, array_shape=(np_,)),
-        dict(name="val", shape=(bn,), dtype="f32", kind="out",
-             resident=False, array_shape=(np_,)),
-        dict(name="acc", shape=(bn, bk), dtype="f32", kind="scratch",
-             resident=True, array_shape=(bn, bk)),
-        dict(name="xn", shape=(bn,), dtype="f32", kind="scratch",
-             resident=True, array_shape=(bn,)),
+        dict(name="center_norms", shape=(bk, 1), dtype="f32", kind="in",
+             resident=False, array_shape=(kp, 1)),
+        dict(name="idx", shape=(1, bn), dtype="i32", kind="out",
+             resident=False, array_shape=(1, np_)),
+        dict(name="val", shape=(1, bn), dtype="f32", kind="out",
+             resident=False, array_shape=(1, np_)),
+        dict(name="acc", shape=(bk, bn), dtype="f32", kind="scratch",
+             resident=True, array_shape=(bk, bn)),
+        dict(name="xn", shape=(1, bn), dtype="f32", kind="scratch",
+             resident=True, array_shape=(1, bn)),
     ]
     return dict(kernel="pdist_argmin",
                 grid=(np_ // bn, kp // bk, dp // bd), storage=store,

@@ -11,6 +11,9 @@ import jax
 import jax.numpy as jnp
 
 MASKED_DIST = 1e30  # additive "infinity" that survives f32 matmul paths
+# f32 contractions run at full f32 precision on every backend: the TPU
+# default would round f32 operands to bf16 (no-op on the CPU).
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def pairwise_sq_dists(x: jax.Array, c: jax.Array) -> jax.Array:
@@ -19,8 +22,12 @@ def pairwise_sq_dists(x: jax.Array, c: jax.Array) -> jax.Array:
     c = c.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     c2 = jnp.sum(c * c, axis=-1)
-    d = x2 - 2.0 * (x @ c.T) + c2[None, :]
-    return jnp.maximum(d, 0.0)
+    # HIGH (three bf16 passes on a TPU, plain f32 elsewhere), not
+    # HIGHEST: inside a vmapped Lloyd loop (B=64, n=1024, d=784) a TPU
+    # v5e returned wrong nearest centers for the HIGHEST form of this
+    # matmul, and right ones for HIGH and DEFAULT.
+    xc = jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGH)
+    return jnp.maximum(x2 - 2.0 * xc + c2[None, :], 0.0)
 
 
 def assign_argmin(x: jax.Array, c: jax.Array, c_mask: jax.Array | None = None):
@@ -41,7 +48,7 @@ def kmeans_update(x: jax.Array, assign: jax.Array, k: int,
     oh = jax.nn.one_hot(assign, k, dtype=jnp.float32)  # -1 rows are all-zero
     if weights is not None:
         oh = oh * weights[:, None].astype(jnp.float32)
-    sums = oh.T @ x.astype(jnp.float32)
+    sums = jnp.matmul(oh.T, x.astype(jnp.float32), precision=HIGHEST)
     counts = jnp.sum(oh, axis=0)
     return sums, counts
 

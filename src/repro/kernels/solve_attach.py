@@ -9,7 +9,7 @@ iteration:
       -> Definition 3.3 induced point labels
 
 The request's points, the evolving (k', d) centers, the per-iteration
-assignments, and the (n, k') distance block all stay resident in VMEM
+assignments, and the (k', n) distance block all stay resident in VMEM
 across the whole while loop — x is read from HBM exactly once and the
 only HBM writes are the four outputs. The legacy staged path re-read x
 twice per Lloyd iteration (once for the assignment kernel, once for the
@@ -44,37 +44,44 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.layout import col, first_min, precision, round_up, row
 from repro.kernels.ref import MASKED_DIST, SOLVE_ATTACH_DTYPES
-
-
-def _round_up(v: int, m: int) -> int:
-    return ((v + m - 1) // m) * m
 
 
 def _kernel(x_ref, c0_ref, tau_ref, cm_ref, pm_ref,
             lbl_ref, mind_ref, ctr_ref, clbl_ref,
             *, max_iters: int, k_real: int):
+    # Every vector is 2-D (kernels/layout.py): per-point quantities are
+    # (1, n_p) rows, per-center ones (kp_p, 1) columns, and distances a
+    # (kp_p, n_p) block with centers on sublanes and points on lanes.
     x = x_ref[0]                                  # (n_p, d_p) store dtype
     xf = x.astype(jnp.float32)
-    xn = jnp.sum(xf * xf, axis=1)                 # (n_p,)
-    cm = cm_ref[0] != 0                           # (kp_p,) bool
-    pm = pm_ref[0] != 0                           # (n_p,) bool
+    xn = row(jnp.sum(xf * xf, axis=1, keepdims=True))         # (1, n_p)
+    cm = col(cm_ref[0]) != 0                                  # (kp_p, 1)
+    pm = pm_ref[0] != 0                                       # (1, n_p)
     taus = tau_ref[...]                           # (k_p, d_p) store dtype
     n_p, kp_p = x.shape[0], c0_ref.shape[1]
+    prec = precision(x.dtype)
+
+    def dot_t(a, b):                              # a @ b.T, f32 result
+        return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                                   precision=prec,
+                                   preferred_element_type=jnp.float32)
+
+    def onehot(a):                                # (kp_p, n_p) bool
+        return a == jax.lax.broadcasted_iota(jnp.int32, (kp_p, n_p), 0)
 
     def assign(centers):
-        # Same expression, same order as ref.assign_argmin: the bf16
-        # dot with preferred f32 equals the oracle's upcast-then-dot.
+        # ref.assign_argmin's expression in its order, transposed: the
+        # bf16 dot with preferred f32 equals the oracle's upcast-then-dot.
         cf = centers.astype(jnp.float32)
-        cn = jnp.sum(cf * cf, axis=1)
-        d = xn[:, None] - 2.0 * jax.lax.dot_general(
-            x, centers, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) + cn[None, :]
-        d = jnp.maximum(d, 0.0)
-        d = jnp.where(cm[None, :], d, MASKED_DIST)
-        idx = jnp.where(pm, jnp.argmin(d, axis=1).astype(jnp.int32), -1)
-        return idx, jnp.where(pm, jnp.min(d, axis=1), 0.0)
+        cn = jnp.sum(cf * cf, axis=1, keepdims=True)          # (kp_p, 1)
+        d = jnp.maximum(xn - 2.0 * dot_t(centers, x) + cn, 0.0)
+        d = jnp.where(cm, d, MASKED_DIST)
+        mind, idx = first_min(d, 0)
+        return jnp.where(pm, idx, -1), jnp.where(pm, mind, 0.0)
 
     def cond(state):
         _, _, it, done = state
@@ -84,19 +91,17 @@ def _kernel(x_ref, c0_ref, tau_ref, cm_ref, pm_ref,
         centers, prev, it, _ = state
         a, _ = assign(centers)
         # one_hot(-1) is all-zero, exactly like ref.kmeans_update.
-        oh = (a[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (n_p, kp_p), 1)).astype(jnp.float32)
+        oh = onehot(a)
         sums = jax.lax.dot_general(
-            oh, xf, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        cnt = jnp.sum(oh, axis=0)
-        new = sums / jnp.maximum(cnt, 1.0)[:, None]
-        new = jnp.where((cnt > 0)[:, None], new,
-                        centers.astype(jnp.float32))
+            oh.astype(x.dtype), x, (((1,), (0,)), ((), ())),
+            precision=prec, preferred_element_type=jnp.float32)
+        cnt = jnp.sum(oh.astype(jnp.float32), axis=1, keepdims=True)
+        new = sums / jnp.maximum(cnt, 1.0)
+        new = jnp.where(cnt > 0, new, centers.astype(jnp.float32))
         return (new.astype(centers.dtype), a, it + 1,
                 jnp.all(a == prev))
 
-    a0 = jnp.full((n_p,), -2, jnp.int32)
+    a0 = jnp.full((1, n_p), -2, jnp.int32)
     centers, _, _, _ = jax.lax.while_loop(
         cond, body, (c0_ref[0], a0, jnp.int32(0), jnp.bool_(False)))
     a, mind = assign(centers)
@@ -106,25 +111,22 @@ def _kernel(x_ref, c0_ref, tau_ref, cm_ref, pm_ref,
     # never sees — mask them out; real columns are bitwise identical.
     cf = centers.astype(jnp.float32)
     tf = taus.astype(jnp.float32)
-    dt = jnp.sum(cf * cf, axis=1)[:, None] - 2.0 * jax.lax.dot_general(
-        centers, taus, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) + jnp.sum(tf * tf, axis=1)[None, :]
+    dt = (jnp.sum(cf * cf, axis=1, keepdims=True) - 2.0 * dot_t(centers, taus)
+          + row(jnp.sum(tf * tf, axis=1, keepdims=True)))    # (kp_p, k_p)
     dt = jnp.maximum(dt, 0.0)
     dt = jnp.where(jax.lax.broadcasted_iota(jnp.int32, dt.shape, 1) < k_real,
                    dt, MASKED_DIST)
-    ctr = jnp.where(cm, jnp.argmin(dt, axis=1).astype(jnp.int32), -1)
+    ctr = jnp.where(cm, first_min(dt, 1)[1], -1)              # (kp_p, 1)
 
     # Definition 3.3 induced labels: ctr[clip(a, 0, k'-1)] as an exact
     # one-hot integer select (vector gather is MXU-hostile on TPU).
-    safe = jnp.clip(a, 0, kp_p - 1)
-    oh2 = safe[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (n_p, kp_p), 1)
-    lbl = jnp.sum(jnp.where(oh2, ctr[None, :], 0), axis=1)
+    lbl = jnp.sum(jnp.where(onehot(jnp.clip(a, 0, kp_p - 1)), ctr, 0),
+                  axis=0, keepdims=True)                      # (1, n_p)
 
     lbl_ref[0] = jnp.where(a >= 0, lbl, -1).astype(jnp.int32)
     mind_ref[0] = mind
     ctr_ref[0] = centers.astype(jnp.float32)
-    clbl_ref[0] = ctr
+    clbl_ref[0] = row(ctr)
 
 
 @functools.partial(jax.jit,
@@ -135,9 +137,7 @@ def _solve_attach(x, c0, tau, cm, pm, *, max_iters: int, dtype: str,
     kp = c0.shape[1]
     k = tau.shape[0]
     store = jnp.float32 if dtype == "f32" else jnp.bfloat16
-    sub = 8 if dtype == "f32" else 16
-    n_p, d_p = _round_up(n, sub), _round_up(d, 128)
-    kp_p, k_p = _round_up(kp, 128), _round_up(k, 128)
+    n_p, d_p, kp_p, k_p = _padded(n, d, kp, k, dtype)
 
     xs = x.astype(store)
     if (n_p, d_p) != (n, d):
@@ -148,35 +148,40 @@ def _solve_attach(x, c0, tau, cm, pm, *, max_iters: int, dtype: str,
     ts = tau.astype(store)
     if (k_p, d_p) != (k, d):
         ts = jnp.zeros((k_p, d_p), store).at[:k, :d].set(ts)
-    cmi = jnp.zeros((B, kp_p), jnp.int32).at[:, :kp].set(
+    # Per-request vectors travel as (B, 1, m) rows: a (1, m) block equals
+    # the array's last two dims, which the TPU tiling rule admits.
+    cmi = jnp.zeros((B, 1, kp_p), jnp.int32).at[:, 0, :kp].set(
         cm.astype(jnp.int32))
-    pmi = jnp.zeros((B, n_p), jnp.int32).at[:, :n].set(pm.astype(jnp.int32))
+    pmi = jnp.zeros((B, 1, n_p), jnp.int32).at[:, 0, :n].set(
+        pm.astype(jnp.int32))
+
+    def per_request(*tail):
+        return pl.BlockSpec((1, *tail), lambda b: (b, 0, 0))
 
     lbl, mind, ctr, clbl = pl.pallas_call(
         functools.partial(_kernel, max_iters=max_iters, k_real=k),
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, n_p, d_p), lambda b: (b, 0, 0)),   # x
-            pl.BlockSpec((1, kp_p, d_p), lambda b: (b, 0, 0)),  # theta0
-            pl.BlockSpec((k_p, d_p), lambda b: (0, 0)),         # tau (resident)
-            pl.BlockSpec((1, kp_p), lambda b: (b, 0)),          # center mask
-            pl.BlockSpec((1, n_p), lambda b: (b, 0)),           # point mask
+            per_request(n_p, d_p),                         # x
+            per_request(kp_p, d_p),                        # theta0
+            pl.BlockSpec((k_p, d_p), lambda b: (0, 0)),    # tau (resident)
+            per_request(1, kp_p),                          # center mask
+            per_request(1, n_p),                           # point mask
         ],
-        out_specs=[
-            pl.BlockSpec((1, n_p), lambda b: (b, 0)),
-            pl.BlockSpec((1, n_p), lambda b: (b, 0)),
-            pl.BlockSpec((1, kp_p, d_p), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, kp_p), lambda b: (b, 0)),
-        ],
+        out_specs=[per_request(1, n_p), per_request(1, n_p),
+                   per_request(kp_p, d_p), per_request(1, kp_p)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, n_p), jnp.int32),
-            jax.ShapeDtypeStruct((B, n_p), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, n_p), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, n_p), jnp.float32),
             jax.ShapeDtypeStruct((B, kp_p, d_p), jnp.float32),
-            jax.ShapeDtypeStruct((B, kp_p), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, kp_p), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(n_p, d_p)),
         interpret=interpret,
     )(xs, cs, ts, cmi, pmi)
-    return (lbl[:, :n], mind[:, :n], ctr[:, :kp, :d], clbl[:, :kp])
+    return (lbl[:, 0, :n], mind[:, 0, :n], ctr[:, :kp, :d],
+            clbl[:, 0, :kp])
 
 
 def solve_attach_fused(x: jax.Array, centers0: jax.Array, tau: jax.Array,
@@ -209,8 +214,22 @@ def solve_attach_fused(x: jax.Array, centers0: jax.Array, tau: jax.Array,
 
 def _padded(n, d, k_prime, k, dtype):
     sub = 8 if dtype == "f32" else 16
-    return (_round_up(n, sub), _round_up(d, 128),
-            _round_up(k_prime, 128), _round_up(k, 128))
+    return (round_up(n, sub), round_up(d, 128),
+            round_up(k_prime, 128), round_up(k, 128))
+
+
+# The kernel's working set is dominated by one request's points: the
+# double-buffered x block plus its f32 working copies (the full-precision
+# f32 contraction splits each operand into bf16 pieces). Eight f32 copies
+# of x bound what the compiler allocates at every serve bucket, so the
+# scoped VMEM limit follows x rather than the compiler's 16 MiB default,
+# which the n=1024, d=784 bucket (~26 MB) exceeds.
+_VMEM_FLOOR = 16 * 2 ** 20
+
+
+def vmem_limit(n_p: int, d_p: int) -> int:
+    """Scoped VMEM limit (bytes) for one (n_p, d_p) request block."""
+    return max(_VMEM_FLOOR, 8 * n_p * d_p * 4 + 2 * 2 ** 20)
 
 
 def block_plan(B: int, n: int, d: int, k_prime: int, k: int,
@@ -232,18 +251,18 @@ def block_plan(B: int, n: int, d: int, k_prime: int, k: int,
         # resident for the whole grid.
         dict(name="tau", shape=(k_p, d_p), dtype=store, kind="in",
              resident=True, array_shape=(k_p, d_p)),
-        dict(name="center_mask", shape=(1, kp_p), dtype="i32", kind="in",
-             resident=False, array_shape=(B, kp_p)),
-        dict(name="point_mask", shape=(1, n_p), dtype="i32", kind="in",
-             resident=False, array_shape=(B, n_p)),
-        dict(name="labels", shape=(1, n_p), dtype="i32", kind="out",
-             resident=False, array_shape=(B, n_p)),
-        dict(name="min_dists", shape=(1, n_p), dtype="f32", kind="out",
-             resident=False, array_shape=(B, n_p)),
+        dict(name="center_mask", shape=(1, 1, kp_p), dtype="i32",
+             kind="in", resident=False, array_shape=(B, 1, kp_p)),
+        dict(name="point_mask", shape=(1, 1, n_p), dtype="i32", kind="in",
+             resident=False, array_shape=(B, 1, n_p)),
+        dict(name="labels", shape=(1, 1, n_p), dtype="i32", kind="out",
+             resident=False, array_shape=(B, 1, n_p)),
+        dict(name="min_dists", shape=(1, 1, n_p), dtype="f32", kind="out",
+             resident=False, array_shape=(B, 1, n_p)),
         dict(name="centers", shape=(1, kp_p, d_p), dtype="f32",
              kind="out", resident=False, array_shape=(B, kp_p, d_p)),
-        dict(name="center_labels", shape=(1, kp_p), dtype="i32",
-             kind="out", resident=False, array_shape=(B, kp_p)),
+        dict(name="center_labels", shape=(1, 1, kp_p), dtype="i32",
+             kind="out", resident=False, array_shape=(B, 1, kp_p)),
     ]
     return dict(kernel="solve_attach", grid=(B,), storage=store,
                 accum="f32", blocks=blk)

@@ -112,9 +112,11 @@ def main() -> None:
 
     from repro.data.gaussian import late_device_stream, structured_devices
     from repro.fed.api import FederationPlan, Session
+    from repro.utils.cache import use_compile_cache
     from repro.utils.compat import make_mesh
     from repro.utils.metrics import clustering_accuracy
 
+    use_compile_cache()
     k, kp, d = args.k, args.k_prime, args.d
     fm = structured_devices(jax.random.PRNGKey(args.seed), k=k, d=d,
                             k_prime=kp, m0=args.devices_per_group,

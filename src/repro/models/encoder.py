@@ -234,8 +234,10 @@ def block_plan(items: int, S: int, d: int, d_ff: int, n_heads: int,
     blk = [
         dict(name="x", shape=(1, S, d), dtype=store, kind="in",
              resident=False, array_shape=(items, S, d)),
-        dict(name="tmask", shape=(1, S), dtype="i32", kind="in",
-             resident=False, array_shape=(items, S)),
+        # per-item vectors travel as (items, 1, m) rows, whose unit
+        # sublane axis a (1, 1, m) block spans whole (the TPU tiling rule)
+        dict(name="tmask", shape=(1, 1, S), dtype="i32", kind="in",
+             resident=False, array_shape=(items, 1, S)),
         dict(name="wq", shape=(d, d), dtype=store, kind="in",
              resident=True, array_shape=(d, d)),
         dict(name="wk", shape=(d, d), dtype=store, kind="in",
@@ -255,8 +257,8 @@ def block_plan(items: int, S: int, d: int, d_ff: int, n_heads: int,
              resident=False, array_shape=(d_ff, d)),
         dict(name="hidden", shape=(S, ft), dtype="f32", kind="scratch",
              resident=True, array_shape=(S, d_ff)),
-        dict(name="out", shape=(1, d), dtype="f32", kind="out",
-             resident=False, array_shape=(items, d)),
+        dict(name="out", shape=(1, 1, d), dtype="f32", kind="out",
+             resident=False, array_shape=(items, 1, d)),
     ]
     return dict(kernel="encoder_fwd", grid=(items, d_ff // ft),
                 storage=store, accum="f32", blocks=blk)

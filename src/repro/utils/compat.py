@@ -1,32 +1,22 @@
-"""JAX version compatibility shims.
+"""The one spelling of mesh and shard_map construction the repo uses.
 
-The production code targets the current public APIs (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``); older pinned containers only
-ship ``jax.experimental.shard_map`` and a ``make_mesh`` without
-``axis_types``. Every mesh/shard_map construction in the repo routes
-through here so the whole stack — including the distributed k-FED paths
-— runs on both.
+Every mesh/shard_map construction routes through here, so the options
+the whole stack relies on (replication checking off, Auto axis types)
+are set in one place.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
     return jax.make_mesh(axis_shapes, axis_names,
                          axis_types=(AxisType.Auto,) * len(axis_names))

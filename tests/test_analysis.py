@@ -262,11 +262,34 @@ class TestKernelChecker:
         ok = dict(bad, blocks=[dict(bad["blocks"][0],
                                     array_shape=(4, 100))])
         assert kernels.check_plan(ok, hw) == []
-        # extent-1 sublane windows are the DMA gather granule
+        # a one-row window over a (rows, d) array is refused like any
+        # partial sublane tile; the (rows, 1, d) gather layout is clean
         granule = dict(bad, blocks=[{"name": "x", "shape": (1, 128),
                                      "dtype": "f32", "kind": "in",
                                      "array_shape": (64, 128)}])
-        assert kernels.check_plan(granule, hw) == []
+        assert _rules(kernels.check_plan(granule, hw)) == [
+            "sublane-misaligned"]
+        gather = dict(bad, blocks=[{"name": "x", "shape": (1, 1, 128),
+                                    "dtype": "f32", "kind": "in",
+                                    "array_shape": (64, 1, 128)}])
+        assert kernels.check_plan(gather, hw) == []
+
+    @pytest.mark.parametrize("block,array,rules", [
+        ((128,), (4096,), ["short-1d-block"]),   # shorter than T(1024)
+        ((256,), (4096,), ["short-1d-block"]),
+        ((2048,), (4096,), []),                  # whole layout tiles
+        ((4096,), (4096,), []),                  # unpartitioned
+        ((100,), (100,), []),
+    ])
+    def test_short_1d_block_lint(self, block, array, rules):
+        """1-D blocks that cut an array below XLA's 1-D layout tile are
+        what the compiler refused in pdist_argmin / kmeans_update."""
+        hw = {"vmem_bytes": 1 << 40}
+        plan = {"kernel": "fab", "grid": (2,), "storage": "f32",
+                "accum": "f32",
+                "blocks": [{"name": "idx", "shape": block, "dtype": "i32",
+                            "kind": "out", "array_shape": array}]}
+        assert _rules(kernels.check_plan(plan, hw)) == rules
 
     def test_bf16_accum_rule(self):
         hw = {"vmem_bytes": 1 << 40}

@@ -510,3 +510,42 @@ def test_bench_cli_unknown_key_and_list():
         env=env, cwd=root, capture_output=True, text=True, timeout=120)
     assert lst.returncode == 0
     assert "table1" in lst.stdout and "attach" in lst.stdout
+
+
+@pytest.mark.parametrize("failing", ["raises", "error_row", None])
+def test_bench_cli_exit_code(monkeypatch, capsys, failing):
+    """`benchmarks.run` prints every bench's rows, and exits 1 when a
+    bench raised or printed an ``ERROR:`` row (0 otherwise)."""
+    import types
+
+    from benchmarks import run as bench_run
+
+    def fake(key, rows):
+        def run(full=False):
+            if key == "raises":
+                raise RuntimeError("bench blew up")
+            return rows
+        mod = types.ModuleType(f"fake_bench_{key}")
+        mod.run = run
+        monkeypatch.setitem(sys.modules, mod.__name__, mod)
+        return mod.__name__
+
+    benches = {"good": fake("good", ["good_row,1.0,fine"]),
+               "raises": fake("raises", []),
+               "error_row": fake("error_row",
+                                 ["child,0,ERROR:'child failed'"])}
+    keys = ["good"] + ([failing] if failing else [])
+    monkeypatch.setattr(bench_run, "BENCHES", benches)
+    monkeypatch.setattr(bench_run, "use_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", ["run", "--only", ",".join(keys)])
+    if failing:
+        with pytest.raises(SystemExit) as exc:
+            bench_run.main()
+        assert exc.value.code == 1
+    else:
+        bench_run.main()
+    out = capsys.readouterr()
+    assert "good_row,1.0,fine" in out.out
+    if failing:
+        assert "ERROR:" in out.out
+        assert f"bench(es) failed: {failing}" in out.err

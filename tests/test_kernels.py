@@ -153,6 +153,51 @@ def test_pairwise_argmin_interpret_autodetect():
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
 
 
+def _on_tpu(monkeypatch):
+    """Pretend the default backend is a TPU (nothing is compiled)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setitem(ops._STATE, "interpret", None)
+
+
+@pytest.mark.parametrize("request_interpret", [
+    lambda: ops._interpret(),                      # env var, first call
+    lambda: ops.set_backend("pallas", interpret=True),
+    lambda: ops.resolve_interpret(True),
+])
+def test_interpret_refused_on_tpu(monkeypatch, request_interpret):
+    """No fallback that hides the device: every way of asking for
+    interpret mode fails by name on a TPU backend."""
+    _on_tpu(monkeypatch)
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
+    before = dict(ops._STATE)
+    with pytest.raises(ops.InterpretOnTPUError):
+        request_interpret()
+    assert ops._STATE == before
+
+
+def test_tpu_default_compiles(monkeypatch):
+    """Without an override a TPU backend resolves to compiled kernels,
+    and REPRO_KERNEL_INTERPRET=0 is still honoured there."""
+    _on_tpu(monkeypatch)
+    monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
+    assert ops.resolve_interpret(None) is False
+    monkeypatch.setitem(ops._STATE, "interpret", None)
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
+    assert ops.resolve_interpret(None) is False
+
+
+def test_kmeans_update_interpret_autodetect():
+    """kmeans_update's interpret default resolves through ops like the
+    other kernels (interpret off the TPU, compiled on it)."""
+    x = jax.random.normal(jax.random.PRNGKey(4), (40, 6))
+    assign = jax.random.randint(jax.random.PRNGKey(5), (40,), -1, 3)
+    sums, cnt = pk_update(x, assign.astype(jnp.int32), 3)
+    rsums, rcnt = ref.kmeans_update(x, assign, 3)
+    np.testing.assert_allclose(np.asarray(sums), np.asarray(rsums),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(rcnt))
+
+
 @pytest.mark.parametrize("impl", ["ref", "pallas"])
 def test_assign_argmin_chunked_matches_monolithic(impl):
     """The streaming driver (fixed-size row tiles) is exact vs the

@@ -159,7 +159,12 @@ KERNEL_SHAPES = [
 def test_kernel_matches_oracle(B, n, d, kp, k, dtype):
     """Interpret-mode kernel vs oracle: integer outputs and centers
     exact (fixed seeds), min-dists to the reduction-order tolerance of
-    the zero-padded lane axis."""
+    the zero-padded lane axis. A min-dist is the expansion
+    ||x||^2 - 2 x.c + ||c||^2, whose rounding grows with ||x||^2 and
+    cancels to ~0 for a point on its center, so its tolerance scales
+    with the point's squared norm: 32 eps ||x||^2, which also covers the
+    kernel compiled for a TPU v5e (15 eps ||x||^2 from the CPU oracle at
+    d=784)."""
     tau, x, c0, cm, pm = _request_batch(n * 13 + k, B, n, d, kp, k)
     ref_out = ref.solve_attach(x, c0, tau, cm, pm, max_iters=7,
                                dtype=dtype)
@@ -167,9 +172,13 @@ def test_kernel_matches_oracle(B, n, d, kp, k, dtype):
                                  dtype=dtype, interpret=True)
     np.testing.assert_array_equal(np.asarray(pal_out[0]),
                                   np.asarray(ref_out[0]))       # labels
-    np.testing.assert_allclose(np.asarray(pal_out[1]),
-                               np.asarray(ref_out[1]),
-                               rtol=1e-4, atol=1e-4)            # min-dist
+    xs = np.asarray(x.astype(jnp.bfloat16) if dtype == "bf16" else x,
+                    np.float32)
+    sq = np.sum(xs * xs, axis=-1)
+    eps = np.finfo(np.float32).eps
+    got, want = np.asarray(pal_out[1]), np.asarray(ref_out[1])
+    assert np.all(np.abs(got - want)
+                  <= 1e-4 + 1e-4 * np.abs(want) + 32 * eps * sq)  # min-dist
     np.testing.assert_allclose(np.asarray(pal_out[2]),
                                np.asarray(ref_out[2]),
                                rtol=1e-4, atol=1e-4)            # centers
