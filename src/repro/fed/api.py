@@ -532,10 +532,12 @@ class Session:
     def stats(self) -> dict:
         """Live serving counters plus the §12 load telemetry: the
         ``"autoscale"`` sub-dict carries the controller's current
-        decision (policy, active shards/batch/ladder, decision count)
-        and the last flush's two-phase dispatch/materialize latency,
-        and ``"plane_compiles"`` the serve plane's compiled-signature
-        count (flat in steady state)."""
+        decision (policy, active shards/batch/ladder, decision count),
+        ``"flush"`` the flush path's cumulative counters (flushes,
+        batches, rows and points stepped with padding, refreshes, host
+        seconds per ``kfed.*`` phase; ``fed/telemetry.py``, observability
+        only, zero after a restore), and ``"plane_compiles"`` the serve
+        plane's compiled-signature count (flat in steady state)."""
         return self.service.stats()
 
     def attach_fn(self):

@@ -23,13 +23,12 @@ queue depth and the pending bucket histogram, both functions of the
 request stream alone — plus the controller's own persisted state
 (previous decision + shrink streak), which rides the schema-v3 service
 checkpoint next to ``tau_meta``. Wall-clock flush telemetry
-(:class:`FlushTelemetry`: the two-phase pipeline's dispatch and
-materialize latency) is recorded and surfaced through
-``Session.stats()`` but deliberately EXCLUDED from the decision inputs:
-wall clock does not replay, and version/fold boundaries depend on batch
-shape, so a latency-driven decision would break the bitwise
-restore-replay guarantee the whole streaming layer is built on. Shard
-count never affects results (per-request labels are
+(``fed/telemetry.py``: host spans and counters of the flush path,
+surfaced as ``Session.stats()["flush"]``) is deliberately EXCLUDED from
+the decision inputs: wall clock does not replay, and version/fold
+boundaries depend on batch shape, so a latency-driven decision would
+break the bitwise restore-replay guarantee the whole streaming layer is
+built on. Shard count never affects results (per-request labels are
 batch-composition-independent), but it follows the same rule so the
 decision *sequence* itself replays bitwise.
 
@@ -41,14 +40,14 @@ rungs have each been seen once — scaling never recompiles
 """
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple, Tuple
 
 import numpy as np
 
 __all__ = ["AUTOSCALE_POLICIES", "AUTOSCALE_IDS", "AutoscaleError",
-           "AutoscaleController", "AutoscaleDecision", "FlushTelemetry",
-           "QueueSnapshot", "bucket_of", "decide", "pow2_ceil",
-           "shards_for", "snapshot_queue"]
+           "AutoscaleController", "AutoscaleDecision", "QueueSnapshot",
+           "bucket_of", "decide", "pow2_ceil", "shards_for",
+           "snapshot_queue"]
 
 AUTOSCALE_POLICIES = ("off", "latency", "throughput")
 
@@ -97,17 +96,6 @@ class QueueSnapshot(NamedTuple):
     pending: int                              # queue depth at the boundary
     hist: Tuple[Tuple[int, int], ...]         # ascending (rung, count)
     mass: Tuple[float, ...] = ()              # per-center decayed fold mass
-
-
-class FlushTelemetry(NamedTuple):
-    """Wall-clock observability of one flush's two-phase pipeline —
-    recorded, surfaced in ``stats()``, and NEVER a decision input (see
-    the module docstring's replay contract)."""
-    dispatch_us: int        # phase 1: every batch's step+fold dispatched
-    materialize_us: int     # phase 2: labels gathered to host
-    batches: int
-    requests: int
-    points: int
 
 
 class AutoscaleDecision(NamedTuple):
@@ -234,7 +222,6 @@ class AutoscaleController:
         self.decision = AutoscaleDecision(self.granted, self.max_batch,
                                           self.base_ladder, 0)
         self.streak = 0
-        self.telemetry: Optional[FlushTelemetry] = None
 
     def observe(self, snap: QueueSnapshot) -> AutoscaleDecision:
         """One flush boundary: fold the snapshot into the controller
@@ -247,11 +234,6 @@ class AutoscaleController:
             base_ladder=self.base_ladder, prev=self.decision,
             streak=self.streak)
         return self.decision
-
-    def record(self, telemetry: FlushTelemetry) -> None:
-        """Attach the flush's wall-clock telemetry (observability only;
-        see the replay contract)."""
-        self.telemetry = telemetry
 
     # -- checkpoint plumbing (the v3 schema arrays) ---------------------
     def state_arrays(self) -> Dict[str, np.ndarray]:
@@ -286,7 +268,7 @@ class AutoscaleController:
         self.streak = int(s[3])
 
     def stats(self) -> dict:
-        d, t = self.decision, self.telemetry
+        d = self.decision
         return {
             "policy": self.policy,
             "shards": d.shards,
@@ -295,7 +277,4 @@ class AutoscaleController:
             "decisions": d.seq,
             "granted_shards": self.granted,
             "max_batch": self.max_batch,
-            "last_dispatch_us": t.dispatch_us if t else None,
-            "last_materialize_us": t.materialize_us if t else None,
-            "last_batches": t.batches if t else None,
         }

@@ -56,7 +56,6 @@ In-flight (submitted, unflushed) requests are NOT part of a checkpoint
 """
 from __future__ import annotations
 
-import time
 import warnings
 from dataclasses import dataclass, field
 from typing import Dict, List, NamedTuple, Optional, Tuple
@@ -68,11 +67,11 @@ import numpy as np
 from repro.checkpoint.store import load_extras, load_pytree, save_pytree
 from repro.core import server
 from repro.fed.autoscale import (AUTOSCALE_IDS, AutoscaleController,
-                                 AutoscaleDecision, FlushTelemetry,
-                                 bucket_of, pow2_ceil, shards_for,
-                                 snapshot_queue)
+                                 AutoscaleDecision, bucket_of, pow2_ceil,
+                                 shards_for, snapshot_queue)
 from repro.fed.plane import ServePlane, ServePlaneError, TauBuffer
 from repro.fed.policy import FoldPolicy, make_policy
+from repro.fed.telemetry import FlushTelemetry
 from repro.utils.deprecation import warn_legacy
 
 REFRESH_MODES = ("sync", "async")
@@ -338,6 +337,9 @@ class AttachService:
             granted=self.plane.n_shards,
             n_axes=len(self.plane.axes) if self.plane.axes else 1,
             base_ladder=tuple(cfg.bucket_sizes))
+        # Host spans and counters of the flush path (fed/telemetry.py):
+        # observability only, never checkpointed.
+        self.telemetry = FlushTelemetry()
         self._base_seed = int(seed)
         self._base_key = jax.random.PRNGKey(self._base_seed)
         self._next_id = int(next_id)
@@ -557,10 +559,51 @@ class AttachService:
         version bump covers both), so every request in one
         flush-and-refresh window maps to exactly one tau version.
         """
-        if self._taubuf.pending:
-            self._taubuf = self._taubuf.commit()
-            self._commit_heads_perm()
-        pending, self._pending = self._pending, []
+        tel = self.telemetry
+        tel.flushes += 1
+        with tel.phase("flush"):
+            if self._taubuf.pending:
+                self._taubuf = self._taubuf.commit()
+                self._commit_heads_perm()
+            pending, self._pending = self._pending, []
+            with tel.phase("bucket"):
+                buckets, decision = self._group(pending)
+            out, self._done = self._done, {}  # undelivered earlier results
+            # Two-phase pipeline: phase 1 DISPATCHES every batch (serve
+            # step, fold scatter, staged refresh — all asynchronous,
+            # chained by dataflow), phase 2 materializes labels on host.
+            # The host never sits between consecutive device batches,
+            # which is what keeps a sharded plane's shards saturated.
+            staged: List[tuple] = []
+            try:
+                for bucket in sorted(buckets):
+                    group = buckets[bucket]
+                    B = decision.batch_size
+                    for lo in range(0, len(group), B):
+                        self._serve_batch(group[lo:lo + B], bucket,
+                                          staged, decision)
+                with tel.phase("deliver"):
+                    self._deliver(staged, out)
+            except BaseException:
+                # A failed batch must not lose work: every dispatched
+                # batch that still materializes drains into the
+                # undelivered buffer; everything else (unserved, or
+                # failed async) requeues by request id.
+                for entry in staged:
+                    if entry[0][0][0] in out:
+                        continue  # already delivered before the failure
+                    try:
+                        self._deliver([entry], out)
+                    except Exception:
+                        pass  # its rids stay out of `out` -> requeued
+                self._done.update(out)
+                self._pending = [it for it in pending
+                                 if it[0] not in out] + self._pending
+                raise
+        return out
+
+    def _group(self, pending) -> Tuple[Dict, AutoscaleDecision]:
+        """The flush's scaling decision and its pad-bucket groups."""
         # The flush boundary is the ONE place scaling decisions land
         # (§12): snapshot the queue (depth + base-ladder histogram —
         # deterministic functions of the request stream, so a restored
@@ -583,46 +626,7 @@ class AttachService:
             buckets.setdefault(
                 self._bucket_key(item[1], decision.ladder),
                 []).append(item)
-        out, self._done = self._done, {}  # undelivered earlier results
-        # Two-phase pipeline: phase 1 DISPATCHES every batch (serve
-        # step, fold scatter, staged refresh — all asynchronous, chained
-        # by dataflow), phase 2 materializes labels on host. The host
-        # never sits between consecutive device batches, which is what
-        # keeps a sharded plane's shards saturated.
-        staged: List[tuple] = []
-        t0 = time.perf_counter()
-        try:
-            for bucket in sorted(buckets):
-                group = buckets[bucket]
-                B = decision.batch_size
-                for lo in range(0, len(group), B):
-                    self._serve_batch(group[lo:lo + B], bucket, staged,
-                                      decision)
-            t1 = time.perf_counter()
-            self._deliver(staged, out)
-            if pending:
-                self.autoscaler.record(FlushTelemetry(
-                    dispatch_us=int((t1 - t0) * 1e6),
-                    materialize_us=int((time.perf_counter() - t1) * 1e6),
-                    batches=len(staged), requests=len(pending),
-                    points=sum(item[1].shape[0] for item in pending)))
-        except BaseException:
-            # A failed batch must not lose work: every dispatched batch
-            # that still materializes drains into the undelivered
-            # buffer; everything else (unserved, or failed async)
-            # requeues by request id.
-            for entry in staged:
-                if entry[0][0][0] in out:
-                    continue  # already delivered before the failure
-                try:
-                    self._deliver([entry], out)
-                except Exception:
-                    pass  # its rids stay out of `out` -> requeued
-            self._done.update(out)
-            self._pending = [it for it in pending
-                             if it[0] not in out] + self._pending
-            raise
-        return out
+        return buckets, decision
 
     def _deliver(self, staged, out) -> None:
         """Phase 2 of a flush: gather each dispatched batch's labels
@@ -714,57 +718,61 @@ class AttachService:
             # right-sized group there drops to one shard).
             B = min(B, pow2_ceil(len(batch)))
             shards = shards_for(B, shards, self.autoscaler.n_axes)
-        if encoded:
-            data = np.zeros((B, n_pad, s_pad, cfg.d), np.float32)
-            tmask = np.zeros((B, n_pad, s_pad), bool)
-        else:
-            data = np.zeros((B, n_pad, cfg.d), np.float32)
-            tmask = None
-        pmask = np.zeros((B, n_pad), bool)
-        kv = np.full((B,), cfg.k_prime, np.int32)
-        rids = np.zeros((B,), np.int64)
-        for i in range(B):
-            rid, arr, k_valid = batch[min(i, len(batch) - 1)]  # pad=repeat
-            n = arr.shape[0]
+        tel = self.telemetry
+        with tel.phase("prep"):
             if encoded:
-                s = arr.shape[1]
-                data[i, :n, :s] = arr
-                tmask[i, :n, :s] = True
+                data = np.zeros((B, n_pad, s_pad, cfg.d), np.float32)
+                tmask = np.zeros((B, n_pad, s_pad), bool)
             else:
-                data[i, :n] = arr
-            pmask[i, :n] = True
-            kv[i] = k_valid
-            rids[i] = rid
-        keys = jax.vmap(lambda r: jax.random.fold_in(self._base_key, r))(
-            jnp.asarray(rids, jnp.uint32))
-        version = self._taubuf.version
-        if encoded:
-            self._encoded_points += sum(
-                item[1].shape[0] for item in batch)
-            if self._head_spec is not None:
+                data = np.zeros((B, n_pad, cfg.d), np.float32)
+                tmask = None
+            pmask = np.zeros((B, n_pad), bool)
+            kv = np.full((B,), cfg.k_prime, np.int32)
+            rids = np.zeros((B,), np.int64)
+            for i in range(B):
+                rid, arr, k_valid = batch[min(i, len(batch) - 1)]  # pad=repeat
+                n = arr.shape[0]
+                if encoded:
+                    s = arr.shape[1]
+                    data[i, :n, :s] = arr
+                    tmask[i, :n, :s] = True
+                else:
+                    data[i, :n] = arr
+                pmask[i, :n] = True
+                kv[i] = k_valid
+                rids[i] = rid
+            keys = jax.vmap(lambda r: jax.random.fold_in(self._base_key, r))(
+                jnp.asarray(rids, jnp.uint32))
+            version = self._taubuf.version
+        with tel.phase("step", rung=n_pad, rows=len(batch)):
+            if encoded:
+                self._encoded_points += sum(
+                    item[1].shape[0] for item in batch)
+                if self._head_spec is not None:
+                    (labels, centers, cmask, weights, preds, cluster,
+                     kept) = self.plane.encoded_routed_step(
+                        self.tau, self.encoder, self.heads, keys,
+                        jnp.asarray(data), jnp.asarray(pmask),
+                        jnp.asarray(tmask), jnp.asarray(kv), shards=shards)
+                    entry = (batch, labels, version, preds, cluster, kept)
+                else:
+                    labels, centers, cmask, weights = self.plane.encode_step(
+                        self.tau, self.encoder, keys, jnp.asarray(data),
+                        jnp.asarray(pmask), jnp.asarray(tmask),
+                        jnp.asarray(kv), shards=shards)
+                    entry = (batch, labels, version)
+            elif self._head_spec is not None:
                 (labels, centers, cmask, weights, preds, cluster,
-                 kept) = self.plane.encoded_routed_step(
-                    self.tau, self.encoder, self.heads, keys,
-                    jnp.asarray(data), jnp.asarray(pmask),
-                    jnp.asarray(tmask), jnp.asarray(kv), shards=shards)
+                 kept) = self.plane.routed_step(
+                    self.tau, self.heads, keys, jnp.asarray(data),
+                    jnp.asarray(pmask), jnp.asarray(kv), shards=shards)
                 entry = (batch, labels, version, preds, cluster, kept)
             else:
-                labels, centers, cmask, weights = self.plane.encode_step(
-                    self.tau, self.encoder, keys, jnp.asarray(data),
-                    jnp.asarray(pmask), jnp.asarray(tmask),
+                labels, centers, cmask, weights = self.plane.step(
+                    self.tau, keys, jnp.asarray(data), jnp.asarray(pmask),
                     jnp.asarray(kv), shards=shards)
                 entry = (batch, labels, version)
-        elif self._head_spec is not None:
-            (labels, centers, cmask, weights, preds, cluster,
-             kept) = self.plane.routed_step(
-                self.tau, self.heads, keys, jnp.asarray(data),
-                jnp.asarray(pmask), jnp.asarray(kv), shards=shards)
-            entry = (batch, labels, version, preds, cluster, kept)
-        else:
-            labels, centers, cmask, weights = self.plane.step(
-                self.tau, keys, jnp.asarray(data), jnp.asarray(pmask),
-                jnp.asarray(kv), shards=shards)
-            entry = (batch, labels, version)
+        tel.stepped(B, n_pad)
         if cfg.fold_reports:
             self._fold(batch, rids, centers, cmask, weights,
                        shards=shards)
@@ -803,21 +811,22 @@ class AttachService:
         return granted
 
     def _fold(self, batch, rids, centers, cmask, weights, shards=None):
-        dev_w = (np.asarray(jnp.sum(weights, axis=1))[:len(batch)]
-                 if self.policy.needs_weight else None)
-        admitted = self._admit_and_fold(
-            rids[:len(batch)], dev_w, centers, cmask,
-            weights if self.cfg.weight_by_core_counts else None,
-            total=len(rids), shards=shards)
-        if not admitted:
-            return
-        self._since_refresh += admitted
-        if self.cfg.refresh_every and (
-                self._since_refresh >= self.cfg.refresh_every):
-            if self.cfg.refresh == "sync":
-                self.refresh()
-            else:
-                self._stage_refresh()
+        with self.telemetry.phase("fold"):
+            dev_w = (np.asarray(jnp.sum(weights, axis=1))[:len(batch)]
+                     if self.policy.needs_weight else None)
+            admitted = self._admit_and_fold(
+                rids[:len(batch)], dev_w, centers, cmask,
+                weights if self.cfg.weight_by_core_counts else None,
+                total=len(rids), shards=shards)
+            if not admitted:
+                return
+            self._since_refresh += admitted
+            if self.cfg.refresh_every and (
+                    self._since_refresh >= self.cfg.refresh_every):
+                if self.cfg.refresh == "sync":
+                    self.refresh()
+                else:
+                    self._stage_refresh()
 
     # ----------------------------------------------------------- refresh --
 
@@ -893,10 +902,12 @@ class AttachService:
         devices + streamed attachments) and swap in the new tau centers
         NOW (one atomic version bump). tau is a traced argument of the
         serve step, so no recompile."""
-        agg, tau = self._refinalize()
-        self._taubuf = self._taubuf.swap_now(self.plane.localize(tau))
-        self._commit_heads_perm()
-        self._since_refresh = 0
+        with self.telemetry.phase("refresh"):
+            self.telemetry.refreshes += 1
+            agg, tau = self._refinalize()
+            self._taubuf = self._taubuf.swap_now(self.plane.localize(tau))
+            self._commit_heads_perm()
+            self._since_refresh = 0
         return agg
 
     def _stage_refresh(self) -> None:
@@ -904,9 +915,11 @@ class AttachService:
         (jax dispatches the re-finalization asynchronously, so serving
         against the active buffer continues while it computes) and
         defer the version-bump swap to the next flush boundary."""
-        _, tau = self._refinalize()
-        self._taubuf = self._taubuf.stage(self.plane.localize(tau))
-        self._since_refresh = 0
+        with self.telemetry.phase("refresh"):
+            self.telemetry.refreshes += 1
+            _, tau = self._refinalize()
+            self._taubuf = self._taubuf.stage(self.plane.localize(tau))
+            self._since_refresh = 0
 
     def _commit_heads_perm(self) -> None:
         """Apply a staged split/retire head re-map (§14 x §16): the
@@ -1215,6 +1228,7 @@ class AttachService:
             "tau_version": self._taubuf.version,
             "refresh_pending": self._taubuf.pending,
             "autoscale": self.autoscaler.stats(),
+            "flush": self.telemetry.stats(),
             "heads": self._heads_stats(),
             "encoder": self._encoder_stats(),
             "drift": {

@@ -199,14 +199,18 @@ def main() -> None:
           f"(capacity {st['capacity']}, policy {st['fold_policy']}), "
           f"refresh cadence {args.refresh_every} ({args.refresh}), "
           f"final tau version {st['tau_version']}")
-    a = st["autoscale"]
+    a, f = st["autoscale"], st["flush"]
     print(f"autoscale[{a['policy']}]: active shards {a['shards']}/"
           f"{a['granted_shards']}, batch {a['batch_size']}/"
           f"{a['max_batch']}, ladder {a['ladder']}, "
           f"{a['decisions']} decisions, "
-          f"{st['plane_compiles']} compiled signatures, last flush "
-          f"dispatch {a['last_dispatch_us']}us / materialize "
-          f"{a['last_materialize_us']}us")
+          f"{st['plane_compiles']} compiled signatures")
+    print(f"flush: {f['flushes']} flushes, {f['batches']} batches, "
+          f"{f['rows_stepped']} rows ({f['points_stepped']} points) "
+          f"stepped, {f['refreshes']} refreshes; host s: prep "
+          f"{f['prep_s']:.3f}, step {f['step_s']:.3f}, fold "
+          f"{f['fold_s']:.3f}, refresh {f['refresh_s']:.3f}, deliver "
+          f"{f['deliver_s']:.3f}")
 
 
 if __name__ == "__main__":

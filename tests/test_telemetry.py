@@ -1,0 +1,116 @@
+"""Flush telemetry (fed/telemetry.py, DESIGN.md §12): the serving layer's
+cumulative flush counters and the ``kfed.*`` phase boundaries they are
+timed at.
+
+The counters are exact functions of the served stream (batches, padded
+rows and points, refreshes); the phase seconds are self times, so a
+nested phase's time is taken out of its parent's. Neither rides a
+checkpoint: a restored service starts at zero.
+"""
+import numpy as np
+import pytest
+
+import jax
+
+from repro.data.gaussian import late_device_stream, structured_devices
+from repro.fed import telemetry
+from repro.fed.api import FederationPlan, Session
+
+K, KP, D = 8, 2, 16
+
+
+@pytest.fixture(scope="module")
+def fixture_round():
+    fm = structured_devices(jax.random.PRNGKey(0), k=K, d=D, k_prime=KP,
+                            m0=4, n_per_comp_dev=20, sep=60.0)
+    rr = Session(FederationPlan(k=K, k_prime=KP, d=D)).run(
+        jax.random.PRNGKey(1), fm.data).detail
+    return fm, rr
+
+
+def _session(rr) -> Session:
+    return Session.from_round(FederationPlan(
+        k=K, k_prime=KP, d=D, capacity=64, batch_size=4,
+        bucket_sizes=(16, 128), refresh_every=4, autoscale="off"), rr)
+
+
+def _requests(fm):
+    """Three requests of 10 points and two of 100."""
+    small = late_device_stream(fm.means, KP, 3, 1, n_range=(10, 11))
+    large = late_device_stream(fm.means, KP, 2, 2, n_range=(100, 101))
+    return [r[0] for r in small + large], [r[2] for r in small + large]
+
+
+def test_flush_counters_count_batches_rows_and_padding(fixture_round):
+    """One flush of 3 x 10 and 2 x 100 points over pads (16, 128) at
+    batch 4: one batch per rung, each padded by repeat to 4 rows, so
+    8 rows and 4 x 16 + 4 x 128 = 576 points stepped for 5 devices and
+    230 real points; the 5 folds cross the refresh cadence of 4 once."""
+    fm, rr = fixture_round
+    sess = _session(rr)
+    reqs, kvs = _requests(fm)
+    sess.serve(reqs, kvs)
+    st = sess.stats()
+    f = st["flush"]
+    assert {k: f[k] for k in ("flushes", "batches", "rows_stepped",
+                              "points_stepped", "refreshes")} == {
+        "flushes": 1, "batches": 2, "rows_stepped": 8,
+        "points_stepped": 576, "refreshes": 1}
+    assert st["served_devices"] == 5 and st["served_points"] == 230
+    assert sess.tau_version == 1
+    phases = {f"{p}_s" for p in telemetry.PHASES}
+    assert phases <= set(f) and all(f[p] >= 0.0 for p in phases)
+    assert f["refresh_s"] > 0.0 and f["step_s"] > 0.0
+    assert not any(k.startswith("last_") for k in st["autoscale"])
+
+
+def test_phase_seconds_are_self_time(monkeypatch):
+    """A nested phase's seconds come out of its parent's: fold 0-6 s
+    holding a refresh 1-4 s gives fold 3 s and refresh 3 s; the flush
+    around them keeps only its own 2 s."""
+    ticks = iter([0.0, 1.0, 2.0, 5.0, 7.0, 8.0])
+    monkeypatch.setattr(telemetry.time, "perf_counter",
+                        lambda: next(ticks))
+    tel = telemetry.FlushTelemetry()
+    tel.flushes += 1
+    with tel.phase("flush"):              # 0 .. 8
+        with tel.phase("fold"):           # 1 .. 7
+            with tel.phase("refresh"):    # 2 .. 5
+                pass
+    assert tel.seconds["refresh"] == 3.0
+    assert tel.seconds["fold"] == 3.0
+    assert tel.seconds["flush"] == 2.0
+    assert tel.stats()["flush_s"] == 2.0 and tel.stats()["flushes"] == 1
+
+
+def test_phase_counts_time_when_the_body_raises(monkeypatch):
+    """A phase that raises still closes its span and books its time,
+    and its parent stays consistent."""
+    ticks = iter([0.0, 1.0, 3.0, 4.0])
+    monkeypatch.setattr(telemetry.time, "perf_counter",
+                        lambda: next(ticks))
+    tel = telemetry.FlushTelemetry()
+    with pytest.raises(RuntimeError):
+        with tel.phase("flush"):
+            with tel.phase("step"):
+                raise RuntimeError("boom")
+    assert tel.seconds["step"] == 2.0 and tel.seconds["flush"] == 2.0
+    assert tel._inner == []
+
+
+def test_counters_never_ride_a_checkpoint(fixture_round, tmp_path):
+    """Observability only: a restore starts every counter at zero, and
+    the restored service serves the rest bitwise like the live one."""
+    fm, rr = fixture_round
+    live = _session(rr)
+    reqs, kvs = _requests(fm)
+    live.serve(reqs[:3], kvs[:3])
+    path = live.save(str(tmp_path / "ck.npz"))
+    restored = Session.restore(path, live.plan)
+    f = restored.stats()["flush"]
+    assert all(v == 0 for v in f.values()), f
+    for a, b in zip(live.serve(reqs[3:], kvs[3:]),
+                    restored.serve(reqs[3:], kvs[3:])):
+        np.testing.assert_array_equal(a, b)
+    assert restored.stats()["flush"]["batches"] == 1
+    assert live.stats()["flush"]["batches"] == 2
