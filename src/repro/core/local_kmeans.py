@@ -21,28 +21,86 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.lloyd import kmeans_pp_init, lloyd, update_centers
 from repro.kernels import ops
 from repro.kernels.ref import MASKED_DIST
 
 
-def project_top_k(A: jax.Array, k_valid, k_max: int,
-                  point_mask: Optional[jax.Array] = None) -> jax.Array:
-    """Projection of rows of A onto the top-k_valid right singular subspace.
+# Step 1's projection is a block subspace iteration on Am^T Am, run to a
+# convergence test (``top_right_subspace``). Every matmul in it runs at
+# Precision.HIGHEST (f32; never the TPU's single bf16 pass). A row stops
+# once every kept Ritz pair's residual meets the backward-error bound
+# ||Am^T Am v_i - theta_i v_i|| <= PROJ_TOL * eps_f32 * theta_1, or at
+# PROJ_MAX_ITERS steps.
+PROJ_TOL = 16.0
+PROJ_MAX_ITERS = 64
 
-    Exact SVD path; see ``subspace_project`` for the iterative TPU-friendly
-    variant used at large n*d.
-    """
-    n, d = A.shape
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _dot(spec, a, b):
+    # einsum's dot_general contracts in place, with no transpose that a
+    # batch could lay out differently: a row's bits are those it has alone.
+    return jnp.einsum(spec, a, b, precision=_HIGHEST)
+
+
+def _start_basis(d: int, p: int) -> np.ndarray:
+    """A fixed orthonormal (d, p) start, the same for every request."""
+    i = np.arange(d, dtype=np.float64)[:, None]
+    j = np.arange(p, dtype=np.float64)[None, :]
+    V = np.cos(0.37 * (i + 1.0) * (j + 1.0)) + 1e-3 * (i - j)
+    return np.linalg.qr(V)[0].astype(np.float32)
+
+
+def top_right_subspace(Am: jax.Array, k_valid, k_max: int):
+    """The top-k_max right singular vectors of ``Am`` as (d, k_max)
+    columns in descending order, and the number of iterations taken.
+
+    A block of p = min(2 k_max, n, d) columns steps V <- qr(Am^T (Am V));
+    after each step the p x p Rayleigh-Ritz problem orders the Ritz
+    vectors, and the loop ends once the first min(k_valid, p) of them
+    pass the residual test above. Columns past p are zero. Finite on an
+    all-zero matrix and on one of rank below p; under vmap a finished
+    row's carry stays as it was, so each row depends on itself alone."""
+    n, d = Am.shape
+    p = min(2 * k_max, n, d)
+    live = jnp.arange(p) < jnp.minimum(jnp.asarray(k_valid, jnp.int32), p)
+    tol = PROJ_TOL * float(np.finfo(np.float32).eps)
+
+    def body(carry):
+        V, _, it, _ = carry
+        W = _dot("nd,np->dp", Am, _dot("nd,dp->np", Am, V))  # Am^T Am V
+        theta, S = jnp.linalg.eigh(_dot("dp,dq->pq", V, W))  # ascending
+        theta, S = theta[::-1], S[:, ::-1]
+        Y, GY = _dot("dp,pq->dq", V, S), _dot("dp,pq->dq", W, S)
+        res = jnp.linalg.norm(GY - Y * theta[None, :], axis=0)
+        ok = res <= tol * jnp.maximum(theta[0], 0.0)
+        return jnp.linalg.qr(GY)[0], Y, it + 1, jnp.all(ok | ~live)
+
+    def cond(carry):
+        return ~carry[3] & (carry[2] < PROJ_MAX_ITERS)
+
+    V0 = jnp.asarray(_start_basis(d, p))
+    _, Y, iters, _ = jax.lax.while_loop(
+        cond, body, (V0, V0, jnp.int32(0), jnp.bool_(False)))
+    kept = min(k_max, p)
+    V = jnp.zeros((d, k_max), jnp.float32).at[:, :kept].set(Y[:, :kept])
+    return V, iters
+
+
+def project_top_k(A: jax.Array, k_valid, k_max: int,
+                  point_mask: Optional[jax.Array] = None):
+    """Projection of rows of A onto the top-k_valid right singular
+    subspace of the masked A, and the iterations it took
+    (:func:`top_right_subspace`)."""
     Af = A.astype(jnp.float32)
     Am = Af if point_mask is None else Af * point_mask[:, None]
-    Vt = jnp.linalg.svd(Am, full_matrices=False)[2]  # (min(n,d), d)
-    rows = min(k_max, Vt.shape[0])
-    V = jnp.zeros((k_max, d), jnp.float32).at[:rows].set(Vt[:rows])
+    V, iters = top_right_subspace(Am, k_valid, k_max)
     rmask = jnp.arange(k_max) < jnp.asarray(k_valid, jnp.int32)
-    V = V * rmask[:, None]
-    return ((Af @ V.T) @ V).astype(A.dtype)
+    V = V * rmask[None, :]
+    return ((Af @ V) @ V.T).astype(A.dtype), iters
 
 
 def subspace_project(A: jax.Array, k_valid, k_max: int,
@@ -84,6 +142,7 @@ class LocalPrepared(NamedTuple):
     theta: jax.Array         # (k_max, d) f32 core-set means
     center_mask: jax.Array   # (k_max,) bool
     core_counts: jax.Array   # (k_max,) |S_r| from the 1/3-margin step
+    proj_iters: jax.Array    # () int32 step-1 iterations (0: fixed-count)
 
 
 def split_local_kw(local_kw: dict):
@@ -108,8 +167,11 @@ def local_prepare(key: jax.Array, A: jax.Array, *, k_max: int,
     pm = jnp.ones((n,), bool) if point_mask is None else point_mask
 
     # -- Step 1: spectral projection.
-    proj = subspace_project if use_subspace_iteration else project_top_k
-    Ahat = proj(A, kv, k_max, point_mask=pm)
+    if use_subspace_iteration:
+        Ahat = subspace_project(A, kv, k_max, point_mask=pm)
+        proj_iters = jnp.int32(0)
+    else:
+        Ahat, proj_iters = project_top_k(A, kv, k_max, point_mask=pm)
 
     # -- Step 2: approximation algorithm on projected data.
     nu, cmask = kmeans_pp_init(key, Ahat, k_max, point_mask=pm, k_valid=kv)
@@ -128,7 +190,7 @@ def local_prepare(key: jax.Array, A: jax.Array, *, k_max: int,
     core_assign = jnp.where(in_core, r, -1)
     theta, core_counts = update_centers(A.astype(jnp.float32), core_assign,
                                         k_max, nu.astype(jnp.float32))
-    return LocalPrepared(theta, cmask, core_counts)
+    return LocalPrepared(theta, cmask, core_counts, proj_iters)
 
 
 def local_kmeans(key: jax.Array, A: jax.Array, *, k_max: int,

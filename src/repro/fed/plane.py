@@ -155,7 +155,8 @@ def _make_step(cfg):
     + Definition 3.3 induced labels in a single ``lloyd_attach``
     dispatch (kernels/solve_attach, DESIGN.md §13). ``cfg.serve_dtype``
     selects f32 (bitwise vs the pre-fusion staged step) or bf16 storage
-    with f32 accumulation."""
+    with f32 accumulation. The fifth output is each row's step-1
+    projection iteration count, (B,) int32."""
     prep_kw, max_iters = split_local_kw(cfg.local_kw)
 
     def step(tau, keys, data, point_mask, k_valid):
@@ -167,7 +168,7 @@ def _make_step(cfg):
             point_mask=point_mask, max_iters=max_iters,
             serve_dtype=cfg.serve_dtype)
         return (labels, centers, prep.center_mask,
-                server.core_weights(prep.core_counts))
+                server.core_weights(prep.core_counts), prep.proj_iters)
 
     return step
 
@@ -215,8 +216,8 @@ def _make_routed_step(cfg, axes=None, axis_sizes=None):
             shards *= int(sz)
 
     def routed(tau, head_params, keys, data, point_mask, k_valid):
-        labels, centers, cmask, weights = base(tau, keys, data,
-                                               point_mask, k_valid)
+        labels, centers, cmask, weights, iters = base(
+            tau, keys, data, point_mask, k_valid)
         B, n_pad, d = data.shape
         C = route_capacity(B * shards, k, cfg.head_capacity)
         S = k * C
@@ -276,7 +277,8 @@ def _make_routed_step(cfg, axes=None, axis_sizes=None):
         preds = ops.moe_combine(ybuf.reshape(S, d),
                                 jnp.where(kept, slot, 0),
                                 kept.astype(jnp.float32), top_k=1)
-        return labels, centers, cmask, weights, preds, cluster, kept
+        return (labels, centers, cmask, weights, preds, cluster, kept,
+                iters)
 
     return routed
 
@@ -295,8 +297,8 @@ def _make_allk_step(cfg):
     k = cfg.k
 
     def allk(tau, head_params, keys, data, point_mask, k_valid):
-        labels, centers, cmask, weights = base(tau, keys, data,
-                                               point_mask, k_valid)
+        labels, centers, cmask, weights, _ = base(tau, keys, data,
+                                                  point_mask, k_valid)
         B = data.shape[0]
         cluster = majority_vote(jnp.where(point_mask, labels, -1),
                                 k).astype(jnp.int32)
@@ -434,6 +436,7 @@ class ServePlane:
         self._enc_routed = {}
         self._signatures = set()
         self.compile_count = 0
+        self.last_proj_iters = None
         self._plane_for(n)
         if getattr(cfg, "heads", "off") != "off":
             self._routed_plane_for(n)
@@ -477,7 +480,7 @@ class ServePlane:
             step_sharded = _shard_map(
                 step, mesh=mesh,
                 in_specs=(P(), spec, spec, spec, spec),
-                out_specs=(spec, spec, spec, spec))
+                out_specs=(spec,) * 5)
 
             def fold_sharded(state, slots, centers, cmask, weights,
                              epochs):
@@ -516,7 +519,7 @@ class ServePlane:
             routed_sharded = _shard_map(
                 routed, mesh=mesh,
                 in_specs=(P(), P(), spec, spec, spec, spec),
-                out_specs=(spec,) * 7)
+                out_specs=(spec,) * 8)
             entry = (jax.jit(routed_sharded), NamedSharding(mesh, spec),
                      NamedSharding(mesh, P()))
         self._routed[s] = entry
@@ -541,7 +544,7 @@ class ServePlane:
             enc_sharded = _shard_map(
                 _make_encode_step(self.cfg), mesh=mesh,
                 in_specs=(P(), P(), spec, spec, spec, spec, spec),
-                out_specs=(spec,) * 4)
+                out_specs=(spec,) * 5)
             entry = (jax.jit(enc_sharded), NamedSharding(mesh, spec),
                      NamedSharding(mesh, P()))
         self._encode[s] = entry
@@ -568,7 +571,7 @@ class ServePlane:
             fn_sharded = _shard_map(
                 fn, mesh=mesh,
                 in_specs=(P(), P(), P(), spec, spec, spec, spec, spec),
-                out_specs=(spec,) * 7)
+                out_specs=(spec,) * 8)
             entry = (jax.jit(fn_sharded), NamedSharding(mesh, spec),
                      NamedSharding(mesh, P()))
         self._enc_routed[s] = entry
@@ -598,8 +601,8 @@ class ServePlane:
             dev = self.mesh.devices.flatten()[0]
             tau = jax.device_put(tau, dev)
             enc_params = jax.device_put(enc_params, dev)
-        return step_fn(tau, enc_params, keys, data, point_mask,
-                       token_mask, k_valid)
+        return self._keep_iters(step_fn(tau, enc_params, keys, data,
+                                        point_mask, token_mask, k_valid))
 
     def encoded_routed_step(self, tau, enc_params, head_params, keys,
                             data, point_mask, token_mask, k_valid,
@@ -626,8 +629,9 @@ class ServePlane:
             tau = jax.device_put(tau, dev)
             enc_params = jax.device_put(enc_params, dev)
             head_params = jax.device_put(head_params, dev)
-        return step_fn(tau, enc_params, head_params, keys, data,
-                       point_mask, token_mask, k_valid)
+        return self._keep_iters(step_fn(tau, enc_params, head_params, keys,
+                                        data, point_mask, token_mask,
+                                        k_valid))
 
     def routed_step(self, tau, head_params, keys, data, point_mask,
                     k_valid, shards=None):
@@ -652,8 +656,8 @@ class ServePlane:
             dev = self.mesh.devices.flatten()[0]
             tau = jax.device_put(tau, dev)
             head_params = jax.device_put(head_params, dev)
-        return step_fn(tau, head_params, keys, data, point_mask,
-                       k_valid)
+        return self._keep_iters(step_fn(tau, head_params, keys, data,
+                                        point_mask, k_valid))
 
     def _count(self, kind: str, s: int, shape) -> None:
         sig = (kind, s, tuple(shape))
@@ -666,7 +670,9 @@ class ServePlane:
         (labels (B, n_pad), centers (B, k', d), center_mask (B, k'),
         core weights (B, k')) — sharded over the batch axis on the
         sharded plane, bitwise identical per request at ANY active
-        shard count (``shards``, default: the full grant)."""
+        shard count (``shards``, default: the full grant). Every step
+        method leaves the batch's Algorithm 1 step-1 iteration counts,
+        (B,) int32 on the device, in :attr:`last_proj_iters`."""
         s = self.n_shards if shards is None else int(shards)
         step_fn, _, sharding, state_sh = self._plane_for(s)
         self._count("step", s, data.shape)
@@ -685,7 +691,14 @@ class ServePlane:
                 jax.device_put(k_valid, sharding))
         elif self.axes:
             tau = jax.device_put(tau, self.mesh.devices.flatten()[0])
-        return step_fn(tau, keys, data, point_mask, k_valid)
+        return self._keep_iters(step_fn(tau, keys, data, point_mask,
+                                        k_valid))
+
+    def _keep_iters(self, out):
+        """Split the compiled step's trailing iteration counts off into
+        :attr:`last_proj_iters`; return the rest."""
+        self.last_proj_iters = out[-1]
+        return out[:-1]
 
     def localize(self, x):
         """Pull a (small) array stranded on an active sub-mesh — e.g. a

@@ -631,18 +631,13 @@ class AttachService:
     def _deliver(self, staged, out) -> None:
         """Phase 2 of a flush: gather each dispatched batch's labels
         (and, with heads on, predictions) to host and hand them with
-        their tau version to the caller."""
-        for entry in staged:
-            if len(entry) == 3:
-                batch, labels_dev, version = entry
-                preds = cl = kept = None
-            else:
-                (batch, labels_dev, version, preds_dev, cl_dev,
-                 kept_dev) = entry
-                preds = np.asarray(preds_dev)
-                cl = np.asarray(cl_dev)
-                kept = np.asarray(kept_dev)
+        their tau version to the caller; the real rows' projection
+        iteration counts go to the flush counters."""
+        for batch, labels_dev, version, iters_dev, *routed in staged:
+            preds, cl, kept = ([np.asarray(x) for x in routed] if routed
+                               else (None, None, None))
             labels = np.asarray(labels_dev)
+            self.telemetry.projected(np.asarray(iters_dev)[:len(batch)])
             for i, (rid, arr, _) in enumerate(batch):
                 if preds is None:
                     out[rid] = (labels[i, :arr.shape[0]], version, None)
@@ -754,24 +749,26 @@ class AttachService:
                         self.tau, self.encoder, self.heads, keys,
                         jnp.asarray(data), jnp.asarray(pmask),
                         jnp.asarray(tmask), jnp.asarray(kv), shards=shards)
-                    entry = (batch, labels, version, preds, cluster, kept)
+                    routed = (preds, cluster, kept)
                 else:
                     labels, centers, cmask, weights = self.plane.encode_step(
                         self.tau, self.encoder, keys, jnp.asarray(data),
                         jnp.asarray(pmask), jnp.asarray(tmask),
                         jnp.asarray(kv), shards=shards)
-                    entry = (batch, labels, version)
+                    routed = ()
             elif self._head_spec is not None:
                 (labels, centers, cmask, weights, preds, cluster,
                  kept) = self.plane.routed_step(
                     self.tau, self.heads, keys, jnp.asarray(data),
                     jnp.asarray(pmask), jnp.asarray(kv), shards=shards)
-                entry = (batch, labels, version, preds, cluster, kept)
+                routed = (preds, cluster, kept)
             else:
                 labels, centers, cmask, weights = self.plane.step(
                     self.tau, keys, jnp.asarray(data), jnp.asarray(pmask),
                     jnp.asarray(kv), shards=shards)
-                entry = (batch, labels, version)
+                routed = ()
+            entry = (batch, labels, version,
+                     self.plane.last_proj_iters) + routed
         tel.stepped(B, n_pad)
         if cfg.fold_reports:
             self._fold(batch, rids, centers, cmask, weights,

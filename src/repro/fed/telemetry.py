@@ -29,7 +29,11 @@ built: ``flushes``, ``batches``, ``rows_stepped`` (batch rows
 dispatched, repeat-padding included), ``points_stepped`` (rows x pad
 rung), ``refreshes`` and each phase's host seconds as self time (a
 nested phase's seconds are taken out of its parent's, so ``fold_s``
-excludes the refreshes it fires).
+excludes the refreshes it fires). Of the delivered real rows (padding
+excluded), ``proj_iters`` sums the iterations Algorithm 1 step 1's
+projection took, ``proj_iters_max`` is the most any row took, and
+``proj_capped`` counts the rows that reached the iteration cap
+(``core.local_kmeans.PROJ_MAX_ITERS``) and so may stop unconverged.
 
 They are observability only: no scaling or refresh decision reads them
 (wall clock does not replay, DESIGN.md §12), no checkpoint carries them,
@@ -43,6 +47,9 @@ import time
 from typing import Dict, List
 
 import jax
+import numpy as np
+
+from repro.core.local_kmeans import PROJ_MAX_ITERS
 
 SPAN_PREFIX = "kfed."
 PHASES = ("flush", "bucket", "prep", "step", "fold", "refresh", "deliver")
@@ -57,6 +64,9 @@ class FlushTelemetry:
         self.rows_stepped = 0
         self.points_stepped = 0
         self.refreshes = 0
+        self.proj_iters = 0
+        self.proj_iters_max = 0
+        self.proj_capped = 0
         self.seconds: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
         self._inner: List[float] = []   # nested seconds per open phase
 
@@ -83,9 +93,19 @@ class FlushTelemetry:
         self.rows_stepped += rows
         self.points_stepped += rows * rung
 
+    def projected(self, iters: np.ndarray) -> None:
+        """Count the projection iterations of one batch's real rows."""
+        if iters.size:
+            self.proj_iters += int(iters.sum())
+            self.proj_iters_max = max(self.proj_iters_max, int(iters.max()))
+            self.proj_capped += int((iters >= PROJ_MAX_ITERS).sum())
+
     def stats(self) -> dict:
         return {"flushes": self.flushes, "batches": self.batches,
                 "rows_stepped": self.rows_stepped,
                 "points_stepped": self.points_stepped,
                 "refreshes": self.refreshes,
+                "proj_iters": self.proj_iters,
+                "proj_iters_max": self.proj_iters_max,
+                "proj_capped": self.proj_capped,
                 **{f"{p}_s": s for p, s in self.seconds.items()}}

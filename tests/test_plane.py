@@ -502,11 +502,12 @@ def test_fused_step_matches_staged_step_sharded():
     fused = _make_step(cfg)
     if NDEV > 1:
         spec = P(("data",))
-        specs = dict(in_specs=(P(), spec, spec, spec, spec),
-                     out_specs=(spec, spec, spec, spec))
+        ins = (P(), spec, spec, spec, spec)
         mesh = _mesh()
-        fused = _shard_map(fused, mesh=mesh, **specs)
-        legacy = _shard_map(legacy, mesh=mesh, **specs)
+        fused = _shard_map(fused, mesh=mesh, in_specs=ins,
+                           out_specs=(spec,) * 5)
+        legacy = _shard_map(legacy, mesh=mesh, in_specs=ins,
+                            out_specs=(spec,) * 4)
 
     rng = np.random.default_rng(NDEV)
     tau = jnp.asarray(rng.normal(size=(K, D)) * 4, jnp.float32)

@@ -239,7 +239,7 @@ def test_serve_dtype_bf16_step_runs():
     kv = jnp.full((2,), 3, jnp.int32)
     keys = jax.vmap(jax.random.fold_in, (None, 0))(
         jax.random.PRNGKey(0), jnp.arange(2))
-    labels, centers, cmask, w = jax.jit(_make_step(cfg))(
+    labels, centers, cmask, w, iters = jax.jit(_make_step(cfg))(
         tau, keys, data, pm, kv)
     assert labels.shape == (2, 32) and labels.dtype == jnp.int32
     assert centers.dtype == jnp.float32

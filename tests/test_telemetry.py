@@ -64,6 +64,25 @@ def test_flush_counters_count_batches_rows_and_padding(fixture_round):
     assert not any(k.startswith("last_") for k in st["autoscale"])
 
 
+def test_flush_counters_count_projection_iterations(fixture_round):
+    """Each delivered real row adds its step-1 iteration count: the
+    mixture's rows all converge (none reaches the cap), each in at
+    least one step; the three repeat-padding rows add nothing."""
+    from repro.core.local_kmeans import PROJ_MAX_ITERS
+    fm, rr = fixture_round
+    sess = _session(rr)
+    reqs, kvs = _requests(fm)
+    sess.serve(reqs, kvs)
+    f = sess.stats()["flush"]
+    assert f["proj_capped"] == 0
+    assert 1 <= f["proj_iters_max"] <= PROJ_MAX_ITERS
+    assert 5 <= f["proj_iters"] <= 5 * f["proj_iters_max"]
+    sess.serve(reqs, kvs)
+    g = sess.stats()["flush"]
+    assert g["proj_iters"] == 2 * f["proj_iters"]
+    assert g["proj_iters_max"] == f["proj_iters_max"]
+
+
 def test_phase_seconds_are_self_time(monkeypatch):
     """A nested phase's seconds come out of its parent's: fold 0-6 s
     holding a refresh 1-4 s gives fold 3 s and refresh 3 s; the flush

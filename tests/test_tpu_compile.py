@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.local_kmeans import project_top_k
 from repro.kernels import kmeans_update as KU
 from repro.kernels import moe_dispatch as MD
 from repro.kernels import pdist_argmin as PA
@@ -121,3 +122,17 @@ def test_moe_compiles(compile_text, kernel, dtype):
         shapes = (((S, d), dtype), ((T * top_k,), I32),
                   ((T * top_k,), F32))
     assert "tpu_custom_call" in compile_text(fn, *shapes)
+
+
+@pytest.mark.parametrize("n", [64, 256, 1024])
+def test_projection_compiles_without_dxd_work(compile_text, n):
+    """Algorithm 1 step 1, vmapped over a batch of 64 at each serve
+    bucket: no per-request (d, d) matrix and no (d + 1)-entry
+    divide-and-conquer work stack, the marks of a full SVD."""
+    def fn(x, kv, pm):
+        return jax.vmap(lambda a, k, m: project_top_k(a, k, K_PRIME, m))(
+            x, kv, pm)
+    text = compile_text(fn, ((64, n, D), F32), ((64,), I32),
+                        ((64, n), BOOL))
+    assert f"f32[64,{D},{D}]" not in text
+    assert f"s32[{D + 1}]" not in text
