@@ -112,9 +112,12 @@ def kfed_shard_map_impl(mesh, data: jax.Array, k: int, k_prime: int, *,
             kz_all = jax.lax.all_gather(
                 jnp.sum(cmask, axis=1).astype(jnp.int32),
                 axes, axis=0, tiled=True)                  # (Z,)
+            sep_all = jax.lax.all_gather(
+                S.report_separation(loc.centers, cmask),
+                axes, axis=0, tiled=True)                  # (Z,)
             base = _flat_axis_index(axes, mesh) * zloc * k_prime
             _, tau, my = S.aggregate_sharded(loc.centers, cmask, kz_all,
-                                             k, axes, base,
+                                             sep_all, k, axes, base,
                                              weights_loc=w_loc)
         else:
             # -- The one-shot communication: gather centers + masks.

@@ -34,7 +34,17 @@ from repro.kernels.ref import MASKED_DIST
 # once every kept Ritz pair's residual meets the backward-error bound
 # ||Am^T Am v_i - theta_i v_i|| <= PROJ_TOL * eps_f32 * theta_1, or at
 # PROJ_MAX_ITERS steps.
+#
+# A kept pair whose Ritz value is below (1 + PROJ_GAP) times the first
+# dropped one's is not held to that bound: the data leave its direction
+# undetermined among dropped ones of about the same value (the noise
+# bulk, when a device holds fewer components than its k^(z)), and any
+# of them gives a rank-k^(z) approximation as good to that factor. At a
+# ratio of 1 + PROJ_GAP or more the error falls by about that factor per
+# step, and 1.25^64 > 1e6 brings it from the start to the f32 floor
+# within PROJ_MAX_ITERS.
 PROJ_TOL = 16.0
+PROJ_GAP = 0.25
 PROJ_MAX_ITERS = 64
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -61,12 +71,14 @@ def top_right_subspace(Am: jax.Array, k_valid, k_max: int):
     A block of p = min(2 k_max, n, d) columns steps V <- qr(Am^T (Am V));
     after each step the p x p Rayleigh-Ritz problem orders the Ritz
     vectors, and the loop ends once the first min(k_valid, p) of them
-    pass the residual test above. Columns past p are zero. Finite on an
+    pass the residual test above, or lie within the gap of the first
+    dropped Ritz value. Columns past p are zero. Finite on an
     all-zero matrix and on one of rank below p; under vmap a finished
     row's carry stays as it was, so each row depends on itself alone."""
     n, d = Am.shape
     p = min(2 * k_max, n, d)
-    live = jnp.arange(p) < jnp.minimum(jnp.asarray(k_valid, jnp.int32), p)
+    k_live = jnp.minimum(jnp.asarray(k_valid, jnp.int32), p)
+    live = jnp.arange(p) < k_live
     tol = PROJ_TOL * float(np.finfo(np.float32).eps)
 
     def body(carry):
@@ -77,6 +89,9 @@ def top_right_subspace(Am: jax.Array, k_valid, k_max: int):
         Y, GY = _dot("dp,pq->dq", V, S), _dot("dp,pq->dq", W, S)
         res = jnp.linalg.norm(GY - Y * theta[None, :], axis=0)
         ok = res <= tol * jnp.maximum(theta[0], 0.0)
+        dropped = jnp.where(k_live < p, theta[jnp.minimum(k_live, p - 1)],
+                            0.0)
+        ok = ok | (theta < (1.0 + PROJ_GAP) * dropped)
         return jnp.linalg.qr(GY)[0], Y, it + 1, jnp.all(ok | ~live)
 
     def cond(carry):

@@ -115,6 +115,33 @@ def attach_absent_devices(center_labels: jax.Array,
     return jnp.where(participation[:, None], center_labels, post)
 
 
+@jax.jit
+def report_separation(centers: jax.Array, mask: jax.Array) -> jax.Array:
+    """(Z,) f32: the smallest squared distance between two valid centers
+    of each device's report (+inf with fewer than two). A device whose
+    points miss one of its k^(z) components splits another in its local
+    solve, and two of its centers then lie within one component's noise.
+    """
+    c = centers.astype(jnp.float32)
+    sq = jnp.sum(c * c, axis=-1)                          # (Z, k')
+    gram = jnp.einsum("zid,zjd->zij", c, c, precision=HIGHEST)
+    d2 = sq[:, :, None] + sq[:, None, :] - 2.0 * gram
+    kp = centers.shape[1]
+    pair = (mask[:, :, None] & mask[:, None, :]
+            & ~jnp.eye(kp, dtype=bool)[None])
+    return jnp.min(jnp.where(pair, d2, jnp.inf), axis=(1, 2))
+
+
+@jax.jit
+def seed_device(kz: jax.Array, separation: jax.Array) -> jax.Array:
+    """Algorithm 2's "pick any z": of the devices with the most local
+    clusters, the one whose closest two centers lie farthest apart
+    (first among ties), so that M does not spend two seeds on one
+    component of a report that split it."""
+    most = kz == jnp.max(kz)
+    return jnp.argmax(jnp.where(most, separation, -1.0)).astype(jnp.int32)
+
+
 # ---------------------------------------------------------------------------
 # Replicated execution (also the vmap simulation path).
 # ---------------------------------------------------------------------------
@@ -131,10 +158,10 @@ def aggregate(device_centers: jax.Array, center_mask: jax.Array, k: int, *,
     flat = device_centers.reshape(Z * kp, d)
     fm = center_mask.reshape(Z * kp)
 
-    # "Pick any z": deterministically pick the device with most local
-    # clusters (maximizes the seeded set, minimizes max-min iterations).
+    # "Pick any z": the device with most local clusters (maximizes the
+    # seeded set, minimizes max-min iterations), best separated first.
     kz = jnp.sum(center_mask, axis=1)
-    z0 = jnp.argmax(kz).astype(jnp.int32)
+    z0 = seed_device(kz, report_separation(device_centers, center_mask))
     init_sel = ((jnp.arange(Z) == z0)[:, None] & center_mask).reshape(-1)
 
     seeds_idx = L.maxmin_seed(flat, fm, init_sel, k)
@@ -185,8 +212,8 @@ class ShardedReducer:
         return jax.lax.psum(x, self.axes)
 
 
-def aggregate_sharded(centers_loc, mask_loc, kz_all, k, axes, base, *,
-                      weights_loc: Optional[jax.Array] = None):
+def aggregate_sharded(centers_loc, mask_loc, kz_all, sep_all, k, axes,
+                      base, *, weights_loc: Optional[jax.Array] = None):
     """Steps 2-8 of Algorithm 2 with the server itself sharded: each chip
     owns its m_loc = Z_loc*k' slice of the device centers; the greedy
     max-min runs as (local argmax -> two scalar all-reduces -> (d,) psum
@@ -195,8 +222,10 @@ def aggregate_sharded(centers_loc, mask_loc, kz_all, k, axes, base, *,
     Selection order matches the replicated server (first-occurrence
     argmax = smallest global index among ties).
 
-    centers_loc: (Z_loc, k', d); mask_loc: (Z_loc, k'); kz_all: (Z,);
-    ``base`` = this shard's first global row index.
+    centers_loc: (Z_loc, k', d); mask_loc: (Z_loc, k'); kz_all and
+    sep_all: (Z,) every device's valid-center count and
+    :func:`report_separation`; ``base`` = this shard's first global row
+    index.
     Returns (M (k, d), tau_centers (k, d), my_labels (Z_loc, k')).
     """
     Z_loc, kp, d = centers_loc.shape
@@ -206,8 +235,8 @@ def aggregate_sharded(centers_loc, mask_loc, kz_all, k, axes, base, *,
     shard = base // m_loc
     red = ShardedReducer(axes, base, m_loc)
 
-    # "Pick any z": the device with most local clusters, first one wins.
-    z0 = jnp.argmax(kz_all).astype(jnp.int32)
+    # "Pick any z": the same device as the replicated server picks.
+    z0 = seed_device(kz_all, sep_all)
     own_rows = jnp.arange(m_loc) // kp == (z0 - shard * Z_loc)
     init_loc = own_rows & fm                              # (m_loc,)
     count0 = red.psum(jnp.sum(init_loc).astype(jnp.int32))

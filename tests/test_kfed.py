@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import kfed as K
+from repro.core import server as S
 from repro.core.local_kmeans import local_kmeans
 from repro.data.gaussian import structured_devices
 from repro.fed.api import FederationPlan, Session
@@ -51,6 +52,45 @@ def test_kfed_seeds_one_center_per_target_cluster():
     means = np.asarray(fm.means)
     d = ((seeds[:, None] - means[None]) ** 2).sum(-1)
     assert len(set(d.argmin(1).tolist())) == 16
+
+
+def test_seed_device_passes_over_a_report_that_split_a_component():
+    """Algorithm 2 seeds M with one device's report. A device whose points
+    missed one of its k^(z) components splits another in its local solve;
+    seeded from it, M spends two seeds on one component and tau merges
+    two others. Of the devices with the most local clusters, the server
+    seeds from the best separated report."""
+    rng = np.random.default_rng(0)
+    k, kp, d = 8, 4, 16
+    means = rng.normal(size=(k, d))
+    means *= 60.0 / np.sqrt(min(((a - b) ** 2).sum()
+                                for i, a in enumerate(means)
+                                for b in means[i + 1:]))
+    comps = [[0, 1, 2, 3], [4, 5, 6, 7]] * 4
+    centers = np.stack([means[c] for c in comps])
+    centers += 0.05 * rng.normal(size=centers.shape)
+    centers[0, 3] = means[0] + 2.0 * rng.normal(size=d) / np.sqrt(d)
+    mask = np.ones((len(comps), kp), bool)
+    mask[1, 3] = False                    # fewer clusters: never the seed
+    agg = S.aggregate(jnp.asarray(centers, jnp.float32), jnp.asarray(mask),
+                      k)
+    assert int(agg.z0) not in (0, 1)
+    seeds = np.asarray(agg.seed_centers)
+    near = ((seeds[:, None] - means[None]) ** 2).sum(-1).argmin(1)
+    assert sorted(near.tolist()) == list(range(k))
+    sep = np.asarray(S.report_separation(jnp.asarray(centers, jnp.float32),
+                                         jnp.asarray(mask)))
+    assert sep[0] < 10.0 and sep[2:].min() > 1000.0
+    kz = jnp.asarray(mask.sum(1))
+    assert int(S.seed_device(kz, jnp.asarray(sep))) == 2 + int(
+        np.argmax(sep[2:]))
+    # Fewer than two valid centers: +inf, and the count still comes first.
+    one = np.zeros_like(mask)
+    one[:, 0] = True
+    assert np.isinf(np.asarray(S.report_separation(
+        jnp.asarray(centers, jnp.float32), jnp.asarray(one)))).all()
+    assert int(S.seed_device(jnp.asarray([1, 2, 2]),
+                             jnp.asarray([np.inf, 5.0, 7.0]))) == 2
 
 
 def test_kfed_heterogeneous_k_valid():

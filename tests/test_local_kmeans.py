@@ -205,3 +205,89 @@ def test_core_set_seeds_match_svd_path(monkeypatch):
     np.testing.assert_allclose(np.asarray(new.theta), np.asarray(old.theta),
                                rtol=1e-5, atol=1e-4)
     assert (np.asarray(new.proj_iters) >= 1).all()
+
+
+# cifar100-3072's reports: n = 100 points of k^(z) in 1..10 components,
+# padded to the one rung of 128, at d = 3,072 (TFF CIFAR-100). A report
+# is short and wide, about 10 points per component against a noise bulk
+# whose top singular value is about sqrt(3072) + sqrt(100).
+CIFAR_D, CIFAR_K, CIFAR_KP, CIFAR_N, CIFAR_PAD = 3072, 100, 10, 100, 128
+
+
+def _cifar_means(rng):
+    mu = rng.normal(size=(CIFAR_K, CIFAR_D))
+    sq = (mu * mu).sum(1)
+    d2 = sq[:, None] + sq[None] - 2.0 * mu @ mu.T + np.eye(CIFAR_K) * 1e30
+    return mu * 60.0 / np.sqrt(d2.min())
+
+
+def _cifar_report(rng, mu, comps):
+    A = np.zeros((CIFAR_PAD, CIFAR_D), np.float32)
+    A[:CIFAR_N] = mu[rng.choice(comps, CIFAR_N)] + rng.normal(
+        size=(CIFAR_N, CIFAR_D))
+    return A
+
+
+def test_short_wide_reports_stop_under_the_cap():
+    """Every row stops before ``PROJ_MAX_ITERS``, and its kept subspace
+    lies within the Davis-Kahan bound of a float64 SVD's. At the stop
+    each kept Ritz residual is at most PROJ_TOL eps theta_1, so the sine
+    of the largest principal angle is at most sqrt(r) PROJ_TOL eps
+    theta_1 / (theta_r - theta_{r+1}) (eigenvalues of Am^T Am); twice
+    that, for the f32 rounding of Am and of the residual itself."""
+    rng = np.random.default_rng(3072)
+    mu = _cifar_means(rng)
+    rows, kvs = [], []
+    for kv in list(range(1, CIFAR_KP + 1)) * 2:
+        rows.append(_cifar_report(rng, mu, rng.choice(CIFAR_K, kv,
+                                                      replace=False)))
+        kvs.append(kv)
+    sub = jax.jit(jax.vmap(
+        lambda a, k: L.top_right_subspace(a, k, CIFAR_KP)))
+    V, iters = sub(jnp.asarray(np.stack(rows)), jnp.asarray(kvs, jnp.int32))
+    V, iters = np.asarray(V), np.asarray(iters)
+    assert (iters >= 1).all() and (iters < L.PROJ_MAX_ITERS).all(), iters
+    eps = float(np.finfo(np.float32).eps)
+    for i, kv in enumerate(kvs):
+        _, s, Vt = np.linalg.svd(rows[i].astype(np.float64),
+                                 full_matrices=False)
+        theta = s * s
+        bound = 2.0 * np.sqrt(kv) * L.PROJ_TOL * eps * theta[0] / (
+            theta[kv - 1] - theta[kv])
+        assert bound < 1e-3, (i, kv, bound)   # the regime has its gap
+        got = _sin_max(Vt[:kv].T, V[i][:, :kv])
+        assert got <= bound, (i, kv, got, bound)
+
+
+def test_missing_component_stops_on_the_determined_subspace():
+    """k^(z) = 10 of which 9 components drew points: the 10th singular
+    value lies in the noise bulk, with no gap to the 11th, and its
+    direction converges too slowly for the cap (64 steps on a v5e). The
+    row stops once the 9 determined directions pass the residual test,
+    with those within the Davis-Kahan bound of float64 (as above), and
+    the 10th Ritz value within 1 + PROJ_GAP of the 11th singular value:
+    a rank-10 approximation as good as the float64 SVD's to that
+    factor."""
+    rng = np.random.default_rng(733)
+    mu = _cifar_means(rng)
+    rows = [_cifar_report(rng, mu, rng.choice(CIFAR_K, 9, replace=False))
+            for _ in range(4)]
+    sub = jax.jit(jax.vmap(
+        lambda a, k: L.top_right_subspace(a, k, CIFAR_KP)))
+    V, iters = sub(jnp.asarray(np.stack(rows)),
+                   jnp.full((len(rows),), CIFAR_KP, jnp.int32))
+    V, iters = np.asarray(V), np.asarray(iters)
+    assert (iters < L.PROJ_MAX_ITERS // 2).all(), iters
+    eps = float(np.finfo(np.float32).eps)
+    for i, A in enumerate(rows):
+        A64 = A.astype(np.float64)
+        _, s, Vt = np.linalg.svd(A64, full_matrices=False)
+        theta = s * s
+        assert theta[8] > 2.0 * theta[9] > 1.9 * theta[10]
+        bound = 2.0 * 3.0 * L.PROJ_TOL * eps * theta[0] / (
+            theta[8] - theta[9])
+        assert _sin_max(Vt[:9].T, V[i][:, :9]) <= bound
+        v = V[i][:, 9]
+        ritz = float(np.sum((A64 @ v) ** 2))
+        assert theta[10] / (1.0 + L.PROJ_GAP) <= ritz <= theta[9] * (
+            1.0 + 1e-6)

@@ -136,3 +136,15 @@ def test_projection_compiles_without_dxd_work(compile_text, n):
                         ((64, n), BOOL))
     assert f"f32[64,{D},{D}]" not in text
     assert f"s32[{D + 1}]" not in text
+
+
+def test_projection_compiles_at_cifar_width(compile_text):
+    """cifar100-3072's step 1: a batch of 64 reports of the one 128 pad
+    at d = 3,072 (24 x 128 lanes, aligned), k' = 10; no (d, d) work."""
+    def fn(x, kv, pm):
+        return jax.vmap(lambda a, k, m: project_top_k(a, k, 10, m))(
+            x, kv, pm)
+    text = compile_text(fn, ((64, 128, 3072), F32), ((64,), I32),
+                        ((64, 128), BOOL))
+    assert "f32[64,3072,3072]" not in text
+    assert "s32[3073]" not in text
