@@ -23,6 +23,7 @@ non-commutative max-min seeding is deferred to :func:`finalize`.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -147,13 +148,31 @@ def seed_device(kz: jax.Array, separation: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+_traces = 0   # times JAX has traced Algorithm 2's program (see traces)
+
+
+def traces() -> int:
+    """How many times :func:`aggregate`'s program has been traced in this
+    process: its Python body runs only when JAX traces it, once per
+    (shape, ``k``, weights present) and again after ``ops.set_backend``,
+    never on a cached call. The serving layer reads it around each refresh
+    (``refresh_builds``)."""
+    return _traces
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
 def aggregate(device_centers: jax.Array, center_mask: jax.Array, k: int, *,
               weights: Optional[jax.Array] = None) -> KFedAggregate:
-    """Steps 2-8 of Algorithm 2 on a full (Z, k', d) center tensor.
+    """Steps 2-8 of Algorithm 2 on a full (Z, k', d) center tensor, as
+    one program compiled once per shape (``k`` static; centers, mask and
+    weights traced). The round, ``finalize`` and every refresh run this
+    same compiled arithmetic, so they agree bitwise.
 
     ``weights``: optional (Z, k') per-center weights for the Lloyd round
     (masked centers never contribute regardless — their labels are -1).
     """
+    global _traces
+    _traces += 1
     Z, kp, d = device_centers.shape
     flat = device_centers.reshape(Z * kp, d)
     fm = center_mask.reshape(Z * kp)
@@ -406,7 +425,12 @@ def finalize(state: ServerState, k: int, *, weighted: bool = False,
     ``decay``: optional ``(now_epoch, half_life)`` — weight every slot by
     its exponential age factor (always weighted; ``weighted`` then only
     controls whether the core-count weights also participate, which they
-    do by construction since decay scales ``state.weights``)."""
+    do by construction since decay scales ``state.weights``).
+
+    The masking here is a few eager element-wise ops, cached by shape;
+    Algorithm 2 is :func:`aggregate`'s compiled program. ``now_epoch``
+    changes at every refresh, so it stays a traced value: it never
+    forces a build."""
     if decay is None:
         mask = state.mask & state.received[:, None]
         return aggregate(state.centers, mask, k,

@@ -534,8 +534,9 @@ class Session:
         ``"autoscale"`` sub-dict carries the controller's current
         decision (policy, active shards/batch/ladder, decision count),
         ``"flush"`` the flush path's cumulative counters (flushes,
-        batches, rows and points stepped with padding, refreshes, host
-        seconds per ``kfed.*`` phase; ``fed/telemetry.py``, observability
+        batches, rows and points stepped with padding, refreshes and
+        those that built Algorithm 2's program anew, host seconds per
+        ``kfed.*`` phase; ``fed/telemetry.py``, observability
         only, zero after a restore), and ``"plane_compiles"`` the serve
         plane's compiled-signature count (flat in steady state)."""
         return self.service.stats()

@@ -894,14 +894,22 @@ class AttachService:
         self._drift_mass = np.asarray(mass, np.float32)
         return agg, tau
 
+    def _counted_refinalize(self):
+        """:meth:`_refinalize`, counted: one refresh, and one build when
+        Algorithm 2's program had to be traced for it."""
+        tel, traced = self.telemetry, server.traces()
+        tel.refreshes += 1
+        out = self._refinalize()
+        tel.refresh_builds += server.traces() != traced
+        return out
+
     def refresh(self) -> server.KFedAggregate:
         """Re-finalize Algorithm 2 over every folded report (round
         devices + streamed attachments) and swap in the new tau centers
         NOW (one atomic version bump). tau is a traced argument of the
         serve step, so no recompile."""
         with self.telemetry.phase("refresh"):
-            self.telemetry.refreshes += 1
-            agg, tau = self._refinalize()
+            agg, tau = self._counted_refinalize()
             self._taubuf = self._taubuf.swap_now(self.plane.localize(tau))
             self._commit_heads_perm()
             self._since_refresh = 0
@@ -913,8 +921,7 @@ class AttachService:
         against the active buffer continues while it computes) and
         defer the version-bump swap to the next flush boundary."""
         with self.telemetry.phase("refresh"):
-            self.telemetry.refreshes += 1
-            _, tau = self._refinalize()
+            _, tau = self._counted_refinalize()
             self._taubuf = self._taubuf.stage(self.plane.localize(tau))
             self._since_refresh = 0
 

@@ -34,6 +34,9 @@ excluded), ``proj_iters`` sums the iterations Algorithm 1 step 1's
 projection took, ``proj_iters_max`` is the most any row took, and
 ``proj_capped`` counts the rows that reached the iteration cap
 (``core.local_kmeans.PROJ_MAX_ITERS``) and so may stop unconverged.
+``refresh_builds`` counts the refreshes during which Algorithm 2's
+program (``core.server.aggregate``) had to be traced anew: one per fold
+state shape, then none.
 
 They are observability only: no scaling or refresh decision reads them
 (wall clock does not replay, DESIGN.md §12), no checkpoint carries them,
@@ -64,6 +67,7 @@ class FlushTelemetry:
         self.rows_stepped = 0
         self.points_stepped = 0
         self.refreshes = 0
+        self.refresh_builds = 0
         self.proj_iters = 0
         self.proj_iters_max = 0
         self.proj_capped = 0
@@ -105,6 +109,7 @@ class FlushTelemetry:
                 "rows_stepped": self.rows_stepped,
                 "points_stepped": self.points_stepped,
                 "refreshes": self.refreshes,
+                "refresh_builds": self.refresh_builds,
                 "proj_iters": self.proj_iters,
                 "proj_iters_max": self.proj_iters_max,
                 "proj_capped": self.proj_capped,

@@ -87,6 +87,9 @@ def set_backend(impl: str, interpret: Optional[bool] = None,
     _STATE["interpret"] = interpret
     if chunk_rows is not None:
         _STATE["chunk_rows"] = chunk_rows
+    # A jitted caller bakes the dispatch in when it is traced: drop every
+    # compiled program so the next call traces under the new backend.
+    jax.clear_caches()
 
 
 def get_backend() -> str:

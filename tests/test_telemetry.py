@@ -133,3 +133,36 @@ def test_counters_never_ride_a_checkpoint(fixture_round, tmp_path):
         np.testing.assert_array_equal(a, b)
     assert restored.stats()["flush"]["batches"] == 1
     assert live.stats()["flush"]["batches"] == 2
+
+
+@pytest.mark.parametrize("drift,capacity", [("off", 40), ("decay", 48)])
+def test_refresh_builds_algorithm_2_once(fixture_round, drift, capacity):
+    """Algorithm 2 is one program compiled once per fold-state shape:
+    the first refresh builds it, and the later ones (each at its own
+    ``now_epoch`` under ``decay``) trace nothing and compile nothing.
+    A capacity no other test folds at makes that first build this
+    test's own."""
+    fm, rr = fixture_round
+    sess = Session.from_round(FederationPlan(
+        k=K, k_prime=KP, d=D, capacity=capacity, batch_size=4,
+        bucket_sizes=(16, 128), refresh_every=4, autoscale="off",
+        drift=drift, drift_half_life=8 if drift == "decay" else 0), rr)
+    reqs, kvs = _requests(fm)
+    compiled = []
+
+    def on_event(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    sess.serve(reqs[:4], kvs[:4])
+    f = sess.stats()["flush"]
+    assert (f["refreshes"], f["refresh_builds"]) == (1, 1)
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for _ in range(3):
+            sess.serve(reqs[:4], kvs[:4])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    f = sess.stats()["flush"]
+    assert (f["refreshes"], f["refresh_builds"]) == (4, 1)
+    assert compiled == []

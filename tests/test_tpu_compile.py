@@ -6,7 +6,9 @@ hand each kernel's jitted dispatch the shapes of the serve path (the
 FEMNIST-shaped d=784 ragged-lane case, the bucket ladder up to n=1024)
 and compile it for a v5e chip that is described, not attached: what the
 chip's compiler would refuse fails here, and the compiled text must hold
-the Mosaic kernel (``tpu_custom_call``). Nothing runs.
+the Mosaic kernel (``tpu_custom_call``). The refresh's Algorithm 2
+program compiles the same way at the cells' fold-state shapes. Nothing
+runs.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and every test worker
@@ -17,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import server
 from repro.core.local_kmeans import project_top_k
 from repro.kernels import kmeans_update as KU
 from repro.kernels import moe_dispatch as MD
@@ -148,3 +151,14 @@ def test_projection_compiles_at_cifar_width(compile_text):
                         ((64, 128), BOOL))
     assert "f32[64,3072,3072]" not in text
     assert "s32[3073]" not in text
+
+
+@pytest.mark.parametrize("capacity,kp,d,k", [(4096, 8, 784, 64),
+                                             (512, 10, 3072, 100)])
+def test_refresh_aggregate_compiles(compile_text, capacity, kp, d, k):
+    """Algorithm 2 as each refresh runs it, one compiled program over
+    the fold state: FEMNIST's 4,096 x k' = 8 slots at d = 784 into
+    k = 64 centers, and CIFAR-100's 512 x 10 at d = 3,072 into 100."""
+    def fn(centers, mask):
+        return server.aggregate(centers, mask, k)
+    compile_text(fn, ((capacity, kp, d), F32), ((capacity, kp), BOOL))
