@@ -60,7 +60,8 @@ def _step_inputs(cfg):
     keys = jax.random.split(kk, B).astype(jnp.uint32).reshape(B, 2)
     kv = jnp.full((B,), KP, jnp.int32)
     enc = init_encoder(ke, cfg.encoder_spec())
-    return tau, enc, keys, data, pmask, tmask, kv
+    # The plan's composed step signature (fed/plane._serve_step).
+    return (tau, enc, None), keys, data, pmask, kv, tmask
 
 
 def _unbatched(cfg):
@@ -71,7 +72,8 @@ def _unbatched(cfg):
         p, x, m, spec, encode_dtype=cfg.encode_dtype))
     serve = jax.jit(plane_mod._make_step(cfg))
 
-    def step(tau, enc, keys, data, pmask, tmask, kv):
+    def step(params, keys, data, pmask, kv, tmask):
+        tau, enc, _ = params
         embs = [enc_fn(enc, data[i], tmask[i]) for i in range(B)]
         return serve(tau, keys, jnp.stack(embs), pmask, kv)
 
@@ -105,7 +107,7 @@ def run(full: bool):
     cfg = _cfg()
     args = _step_inputs(cfg)
     repeats = 11 if full else 5
-    fused = jax.jit(plane_mod._make_encode_step(cfg))
+    fused = jax.jit(plane_mod._serve_step(cfg))
     unbatched = _unbatched(cfg)
     pts = B * N
     rows = []

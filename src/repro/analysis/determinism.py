@@ -308,7 +308,7 @@ def trace_artifacts(include_sharded: Optional[bool] = None
     arts: List[Artifact] = []
     skipped: List[str] = []
 
-    step = plane_mod._make_step(cfg)
+    step = plane_mod._serve_step(cfg)
     arts.append(Artifact(
         "serve_step", jax.make_jaxpr(step)(*_step_args(cfg)), Contract()))
 
@@ -318,13 +318,12 @@ def trace_artifacts(include_sharded: Optional[bool] = None
     # int/bool overwrite (an accumulating float scatter here would be
     # a replay hazard).
     hcfg = _smoke_cfg(heads=True)
-    routed = plane_mod._make_routed_step(hcfg)
+    routed = plane_mod._serve_step(hcfg)
     tau_s, keys_s, data_s, pmask_s, kv_s = _step_args(hcfg)
+    routed_args = ((tau_s, None, _heads_struct(hcfg)), keys_s, data_s,
+                   pmask_s, kv_s)
     arts.append(Artifact(
-        "routed_step",
-        jax.make_jaxpr(routed)(tau_s, _heads_struct(hcfg), keys_s,
-                               data_s, pmask_s, kv_s),
-        Contract()))
+        "routed_step", jax.make_jaxpr(routed)(*routed_args), Contract()))
 
     # The §17 encode+serve step: the zoo encoder forward fused ahead of
     # the label body. Encoding is pure matmul/softmax on its inputs —
@@ -332,14 +331,13 @@ def trace_artifacts(include_sharded: Optional[bool] = None
     # is the plain serve step's (the solve's keyed RNG still threads
     # from the request keys).
     ecfg = _smoke_cfg(encoder=True)
-    enc_step = plane_mod._make_encode_step(ecfg)
+    enc_step = plane_mod._serve_step(ecfg)
     tau_e, keys_e, _, pmask_e, kv_e = _step_args(ecfg)
     data_e, tmask_e = _encode_args(ecfg)
+    enc_args = ((tau_e, _encoder_struct(ecfg), None), keys_e, data_e,
+                pmask_e, kv_e, tmask_e)
     arts.append(Artifact(
-        "encode_step",
-        jax.make_jaxpr(enc_step)(tau_e, _encoder_struct(ecfg), keys_e,
-                                 data_e, pmask_e, tmask_e, kv_e),
-        Contract()))
+        "encode_step", jax.make_jaxpr(enc_step)(*enc_args), Contract()))
 
     def fold(state, slots, centers, cmask, weights, epochs):
         return server.aggregate_incremental(state, slots, centers, cmask,
@@ -397,27 +395,23 @@ def trace_artifacts(include_sharded: Optional[bool] = None
                      fold_leaves=leaves)))
         plane_h = plane_mod.ServePlane(hcfg, mesh=mesh,
                                        serve_axes=("data",))
-        routed_sh = plane_h._routed_plane_for(s)[0]
+        routed_sh = plane_h._plane_for(s)[0]
         # Sharded: the global keep/overflow ranking all_gathers the
         # int32 cluster votes (deterministic shard-order tiling) —
         # exactly the fold's collective allowance, nothing else.
         arts.append(Artifact(
             "routed_step_sharded",
-            jax.make_jaxpr(routed_sh)(tau_s, _heads_struct(hcfg),
-                                      keys_s, data_s, pmask_s, kv_s),
+            jax.make_jaxpr(routed_sh)(*routed_args),
             Contract(allow_collectives=frozenset({"all_gather"}))))
         # Sharded §17 encode+serve: the batch axis stays embarrassingly
         # parallel through the encode stage (encoder params replicated
         # like tau), so no collective is allowed here either.
         plane_e = plane_mod.ServePlane(ecfg, mesh=mesh,
                                        serve_axes=("data",))
-        enc_sh = plane_e._encode_plane_for(s)[0]
+        enc_sh = plane_e._plane_for(s)[0]
         arts.append(Artifact(
             "encode_step_sharded",
-            jax.make_jaxpr(enc_sh)(tau_e, _encoder_struct(ecfg),
-                                   keys_e, data_e, pmask_e, tmask_e,
-                                   kv_e),
-            Contract()))
+            jax.make_jaxpr(enc_sh)(*enc_args), Contract()))
     else:
         skipped.extend(["serve_step_sharded", "fold_sharded",
                         "routed_step_sharded", "encode_step_sharded"])

@@ -20,20 +20,24 @@ single host or shard_mapped over a mesh:
     an exact psum). Slot admission itself stays host-side in
     ``fed/policy.py`` and is shard-deterministic by contract — the plane
     only ever executes an already-decided ``(B,)`` slot vector.
-  * **routed personalization step** (§16, ``heads != "off"``) — the
-    serve step FUSED with cluster-routed per-request predictions:
-    majority-vote one cluster per request from its Theorem 3.2 labels,
-    ``moe_dispatch``-gather whole requests into per-cluster head
-    queues (clusters are the experts), run each queue through ITS head
-    from the ``models``/``configs`` zoo, ``moe_combine`` back to
-    request order. Same cache/versioning discipline as the plain step;
-    the label outputs stay bitwise-identical to the heads=off plane.
-  * **encode stage** (§17, ``encoder != "off"``) — a zoo encoder
-    forward fused IN FRONT of the plain or routed step: devices submit
-    raw ``(n, seq, d)`` token/patch sequences, one jitted dispatch
-    embeds them (masked-mean pooled to ``d``) and runs the unchanged
-    solve+attach on the embeddings. Encoder params ride replicated
-    like tau; ``encoder=off`` planes are bitwise-untouched.
+  * **optional stages** — the plan's ONE serve step is composed from
+    the stages it turns on (:func:`_serve_step`), one jitted dispatch
+    and one compiled entry per active shard count:
+
+    - **encode** (§17, ``encoder != "off"``), in front: devices submit
+      raw ``(n, seq, d)`` token/patch sequences; a zoo encoder forward
+      embeds them (masked-mean pooled to ``d``) and the unchanged
+      solve+attach runs on the embeddings.
+    - **routed heads** (§16, ``heads != "off"``), behind:
+      majority-vote one cluster per request from its Theorem 3.2
+      labels, ``moe_dispatch``-gather whole requests into per-cluster
+      head queues (clusters are the experts), run each queue through
+      ITS head from the ``models``/``configs`` zoo, ``moe_combine``
+      back to request order.
+
+    Stage params ride replicated like tau; the label outputs stay
+    bitwise-identical to the plain step, and a plan with both stages
+    off runs the plain step itself.
   * **double-buffered tau** (:class:`TauBuffer`) — serving reads
     ``bufs[active]``; a refresh builds the standby buffer while serving
     continues, and the swap is an atomic version bump. Every served
@@ -57,7 +61,7 @@ two device computations of the hot path and their mesh mapping.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -314,49 +318,36 @@ def _make_allk_step(cfg):
     return allk
 
 
-def _make_encode_fn(cfg):
-    """The ingestion-encoder forward (DESIGN.md §17) as the plane's
-    prepended stage: (B, n, S, d) raw token/patch sequences + (B, n, S)
-    token masks -> (B, n, d) f32 embeddings, through the zoo encoder
-    at the plan's ``encode_dtype`` (bf16 storage / f32 accumulation)."""
-    from repro.models import encoder as enc_mod
-    spec = cfg.encoder_spec()
+def _serve_step(cfg, axes=None, axis_sizes=None):
+    """THE plan's serve step, composed from the stages the plan turns
+    on. With encoder and heads off it IS :func:`_make_step`'s ``step``
+    (the plain program, module ``jit_step``: no wrapper, no extra
+    argument). Otherwise the §17 encode stage runs in front of the
+    label body and the §16 routing (:func:`_make_routed_step`, with the
+    sharded plane's ``axes``/``axis_sizes``) behind it, in one
+    dispatch, called as ``step((tau, encoder, heads), keys, data,
+    point_mask, k_valid[, token_mask])`` with ``None`` for a stage the
+    plan leaves off. Outputs: the plain five, or the routed eight."""
+    encoded = getattr(cfg, "encoder", "off") != "off"
+    routed = getattr(cfg, "heads", "off") != "off"
+    if not (encoded or routed):
+        return _make_step(cfg)
+    body = (_make_routed_step(cfg, axes=axes, axis_sizes=axis_sizes)
+            if routed else _make_step(cfg))
+    if encoded:
+        from repro.models import encoder as enc_mod
+        enc_spec = cfg.encoder_spec()
 
-    def encode(enc_params, data, token_mask):
-        return enc_mod.apply_encoder(enc_params, data, token_mask, spec,
-                                     encode_dtype=cfg.encode_dtype)
-
-    return encode
-
-
-def _make_encode_step(cfg):
-    """Encode stage fused in front of THE serve-step body: one jitted
-    dispatch encodes the raw sequences and runs the unchanged
-    solve+attach on the embeddings — the (B, n, d) latent batch never
-    round-trips to host between the stages."""
-    base = _make_step(cfg)
-    encode = _make_encode_fn(cfg)
-
-    def step(tau, enc_params, keys, data, point_mask, token_mask,
-             k_valid):
-        emb = encode(enc_params, data, token_mask)
-        return base(tau, keys, emb, point_mask, k_valid)
-
-    return step
-
-
-def _make_encoded_routed_step(cfg, axes=None, axis_sizes=None):
-    """Encode stage fused in front of the routed personalization step:
-    the routed body (labels, vote, dispatch, heads, combine) operates
-    on the embeddings unchanged, so the per-cluster heads serve in the
-    SAME latent space the attachment clustered."""
-    routed = _make_routed_step(cfg, axes=axes, axis_sizes=axis_sizes)
-    encode = _make_encode_fn(cfg)
-
-    def step(tau, enc_params, head_params, keys, data, point_mask,
-             token_mask, k_valid):
-        emb = encode(enc_params, data, token_mask)
-        return routed(tau, head_params, keys, emb, point_mask, k_valid)
+    def step(params, keys, data, point_mask, k_valid, token_mask=None):
+        tau, enc_params, head_params = params
+        if encoded:
+            # (B, n, S, d) sequences -> (B, n, d) f32 embeddings at the
+            # plan's encode_dtype; the latents never round-trip to host.
+            data = enc_mod.apply_encoder(enc_params, data, token_mask,
+                                         enc_spec,
+                                         encode_dtype=cfg.encode_dtype)
+        lead = (tau, head_params) if routed else (tau,)
+        return body(*lead, keys, data, point_mask, k_valid)
 
     return step
 
@@ -424,28 +415,21 @@ class ServePlane:
         # aggregate footprint across concurrent shards stays bounded.
         self.chunk_rows = ops.plan_chunk_rows(self.n_shards)
         # Per-active-shard-count compiled entries (the §12 multi-spec
-        # cache): s -> (step_jit, fold_jit | None, sharding | None).
-        # Entries are built once and kept forever; together with jax's
-        # shape-keyed jit cache, every (shards, batch, bucket) triple
-        # compiles exactly once. ``compile_count`` counts first-seen
-        # (kind, shards, shape) signatures — what the autoscale tests
-        # and the benchmark assert stays flat in steady state.
+        # cache): s -> (step_jit, fold_jit | None, batch sharding | None,
+        # param sharding | None). The step is the plan's ONE composed
+        # step (:func:`_serve_step`). Entries are built once and kept
+        # forever; together with jax's shape-keyed jit cache, every
+        # (shards, batch, bucket) triple compiles exactly once.
+        # ``compile_count`` counts first-seen (kind, shards, shape)
+        # signatures — what the autoscale tests and the benchmark
+        # assert stays flat in steady state.
         self._planes = {}
-        self._routed = {}
-        self._encode = {}
-        self._enc_routed = {}
         self._signatures = set()
         self.compile_count = 0
         self.last_proj_iters = None
+        self._has_encoder = getattr(cfg, "encoder", "off") != "off"
+        self._has_heads = getattr(cfg, "heads", "off") != "off"
         self._plane_for(n)
-        if getattr(cfg, "heads", "off") != "off":
-            self._routed_plane_for(n)
-        # The §17 encode entries build eagerly too — and ONLY when the
-        # encoder is on, so encoder=off planes are bitwise-untouched.
-        if getattr(cfg, "encoder", "off") != "off":
-            self._encode_plane_for(n)
-            if getattr(cfg, "heads", "off") != "off":
-                self._encoded_routed_plane_for(n)
 
     # ------------------------------------------------------------------
     def _submesh(self, s: int):
@@ -455,8 +439,9 @@ class ServePlane:
         return Mesh(self.mesh.devices.flatten()[:s], self.axes)
 
     def _plane_for(self, s: int):
-        """The compiled (step, fold, sharding) entry for an active
-        shard count ``s`` — built on first use, cached forever."""
+        """The compiled (step, fold, batch sharding, param sharding)
+        entry for an active shard count ``s`` — built on first use,
+        cached forever."""
         entry = self._planes.get(s)
         if entry is not None:
             return entry
@@ -469,18 +454,21 @@ class ServePlane:
                 f"shards={s} is invalid: multi-axis serve_axes "
                 f"{self.axes!r} only switch between 1 and the full "
                 f"grant ({self.n_shards})")
-        step = _make_step(self.cfg)
         if s == 1:
-            entry = (jax.jit(step), None, None, None)
+            entry = (jax.jit(_serve_step(self.cfg)), None, None, None)
         else:
             from jax.sharding import NamedSharding
             mesh = self.mesh if s == self.n_shards else self._submesh(s)
             axes = self.axes
             spec = P(axes)
+            sizes = tuple(int(mesh.shape[a]) for a in axes)
+            # Params (tau, encoder and head weights) ride replicated;
+            # every batch array splits over the batch axis.
+            n_batch = 5 if self._has_encoder else 4
             step_sharded = _shard_map(
-                step, mesh=mesh,
-                in_specs=(P(), spec, spec, spec, spec),
-                out_specs=(spec,) * 5)
+                _serve_step(self.cfg, axes=axes, axis_sizes=sizes),
+                mesh=mesh, in_specs=(P(),) + (spec,) * n_batch,
+                out_specs=spec)
 
             def fold_sharded(state, slots, centers, cmask, weights,
                              epochs):
@@ -498,205 +486,65 @@ class ServePlane:
         self._planes[s] = entry
         return entry
 
-    def _routed_plane_for(self, s: int):
-        """The compiled routed-step entry for an active shard count —
-        the §16 sibling of :meth:`_plane_for` (which it calls first, so
-        shard-count validation and the label plane stay the single
-        source of truth). head_params ride replicated like tau."""
-        entry = self._routed.get(s)
-        if entry is not None:
-            return entry
-        self._plane_for(s)
-        if s == 1:
-            entry = (jax.jit(_make_routed_step(self.cfg)), None, None)
-        else:
-            from jax.sharding import NamedSharding
-            mesh = self.mesh if s == self.n_shards else self._submesh(s)
-            sizes = tuple(int(mesh.shape[a]) for a in self.axes)
-            routed = _make_routed_step(self.cfg, axes=self.axes,
-                                       axis_sizes=sizes)
-            spec = P(self.axes)
-            routed_sharded = _shard_map(
-                routed, mesh=mesh,
-                in_specs=(P(), P(), spec, spec, spec, spec),
-                out_specs=(spec,) * 8)
-            entry = (jax.jit(routed_sharded), NamedSharding(mesh, spec),
-                     NamedSharding(mesh, P()))
-        self._routed[s] = entry
-        return entry
-
-    def _encode_plane_for(self, s: int):
-        """The compiled encode+serve entry for an active shard count —
-        the §17 sibling of :meth:`_plane_for` (which it calls first, so
-        shard-count validation stays the single source of truth).
-        Encoder params ride replicated like tau; the raw-sequence batch
-        and its token mask shard over the batch axis with the rest."""
-        entry = self._encode.get(s)
-        if entry is not None:
-            return entry
-        self._plane_for(s)
-        if s == 1:
-            entry = (jax.jit(_make_encode_step(self.cfg)), None, None)
-        else:
-            from jax.sharding import NamedSharding
-            mesh = self.mesh if s == self.n_shards else self._submesh(s)
-            spec = P(self.axes)
-            enc_sharded = _shard_map(
-                _make_encode_step(self.cfg), mesh=mesh,
-                in_specs=(P(), P(), spec, spec, spec, spec, spec),
-                out_specs=(spec,) * 5)
-            entry = (jax.jit(enc_sharded), NamedSharding(mesh, spec),
-                     NamedSharding(mesh, P()))
-        self._encode[s] = entry
-        return entry
-
-    def _encoded_routed_plane_for(self, s: int):
-        """The compiled encode+routed entry (§17 x §16): encoder AND
-        head params replicated, everything else sharded over the batch
-        axis."""
-        entry = self._enc_routed.get(s)
-        if entry is not None:
-            return entry
-        self._plane_for(s)
-        if s == 1:
-            entry = (jax.jit(_make_encoded_routed_step(self.cfg)),
-                     None, None)
-        else:
-            from jax.sharding import NamedSharding
-            mesh = self.mesh if s == self.n_shards else self._submesh(s)
-            sizes = tuple(int(mesh.shape[a]) for a in self.axes)
-            fn = _make_encoded_routed_step(self.cfg, axes=self.axes,
-                                           axis_sizes=sizes)
-            spec = P(self.axes)
-            fn_sharded = _shard_map(
-                fn, mesh=mesh,
-                in_specs=(P(), P(), P(), spec, spec, spec, spec, spec),
-                out_specs=(spec,) * 8)
-            entry = (jax.jit(fn_sharded), NamedSharding(mesh, spec),
-                     NamedSharding(mesh, P()))
-        self._enc_routed[s] = entry
-        return entry
-
-    def encode_step(self, tau, enc_params, keys, data, point_mask,
-                    token_mask, k_valid, shards=None):
-        """Serve one (B, n_pad, seq_pad, d) batch of raw token/patch
-        sequences: encode to (B, n_pad, d) embeddings and run THE serve
-        step on them in one fused dispatch (DESIGN.md §17). Returns
-        exactly the :meth:`step` quadruple — the fold reports are
-        computed in latent space, so fold/drift/autoscale downstream
-        are unchanged."""
-        s = self.n_shards if shards is None else int(shards)
-        step_fn, sharding, state_sh = self._encode_plane_for(s)
-        self._count("encode", s, data.shape)
-        if sharding is not None:
-            tau = jax.device_put(tau, state_sh)
-            enc_params = jax.device_put(enc_params, state_sh)
-            keys, data, point_mask, token_mask, k_valid = (
-                jax.device_put(keys, sharding),
-                jax.device_put(data, sharding),
-                jax.device_put(point_mask, sharding),
-                jax.device_put(token_mask, sharding),
-                jax.device_put(k_valid, sharding))
-        elif self.axes:
-            dev = self.mesh.devices.flatten()[0]
-            tau = jax.device_put(tau, dev)
-            enc_params = jax.device_put(enc_params, dev)
-        return self._keep_iters(step_fn(tau, enc_params, keys, data,
-                                        point_mask, token_mask, k_valid))
-
-    def encoded_routed_step(self, tau, enc_params, head_params, keys,
-                            data, point_mask, token_mask, k_valid,
-                            shards=None):
-        """:meth:`encode_step` through the per-cluster heads: the
-        routed septuple of :meth:`routed_step`, with both the
-        attachment and the head forwards operating on the encoded
-        embeddings."""
-        s = self.n_shards if shards is None else int(shards)
-        step_fn, sharding, state_sh = self._encoded_routed_plane_for(s)
-        self._count("enc_routed", s, data.shape)
-        if sharding is not None:
-            tau = jax.device_put(tau, state_sh)
-            enc_params = jax.device_put(enc_params, state_sh)
-            head_params = jax.device_put(head_params, state_sh)
-            keys, data, point_mask, token_mask, k_valid = (
-                jax.device_put(keys, sharding),
-                jax.device_put(data, sharding),
-                jax.device_put(point_mask, sharding),
-                jax.device_put(token_mask, sharding),
-                jax.device_put(k_valid, sharding))
-        elif self.axes:
-            dev = self.mesh.devices.flatten()[0]
-            tau = jax.device_put(tau, dev)
-            enc_params = jax.device_put(enc_params, dev)
-            head_params = jax.device_put(head_params, dev)
-        return self._keep_iters(step_fn(tau, enc_params, head_params, keys,
-                                        data, point_mask, token_mask,
-                                        k_valid))
-
-    def routed_step(self, tau, head_params, keys, data, point_mask,
-                    k_valid, shards=None):
-        """Serve one (B, n_pad, d) batch THROUGH the per-cluster heads
-        (DESIGN.md §16). Returns the :meth:`step` quadruple plus
-        (preds (B, d) f32, cluster (B,) i32, kept (B,) bool) — preds
-        are zero and kept False where the request overflowed its
-        cluster's dispatch queue. The label quadruple is
-        bitwise-identical to :meth:`step` on the same inputs."""
-        s = self.n_shards if shards is None else int(shards)
-        step_fn, sharding, state_sh = self._routed_plane_for(s)
-        self._count("routed", s, data.shape)
-        if sharding is not None:
-            tau = jax.device_put(tau, state_sh)
-            head_params = jax.device_put(head_params, state_sh)
-            keys, data, point_mask, k_valid = (
-                jax.device_put(keys, sharding),
-                jax.device_put(data, sharding),
-                jax.device_put(point_mask, sharding),
-                jax.device_put(k_valid, sharding))
-        elif self.axes:
-            dev = self.mesh.devices.flatten()[0]
-            tau = jax.device_put(tau, dev)
-            head_params = jax.device_put(head_params, dev)
-        return self._keep_iters(step_fn(tau, head_params, keys, data,
-                                        point_mask, k_valid))
-
     def _count(self, kind: str, s: int, shape) -> None:
         sig = (kind, s, tuple(shape))
         if sig not in self._signatures:
             self._signatures.add(sig)
             self.compile_count += 1
 
-    def step(self, tau, keys, data, point_mask, k_valid, shards=None):
-        """Serve one fixed-shape (B, n_pad, d) batch. Returns
-        (labels (B, n_pad), centers (B, k', d), center_mask (B, k'),
-        core weights (B, k')) — sharded over the batch axis on the
-        sharded plane, bitwise identical per request at ANY active
-        shard count (``shards``, default: the full grant). Every step
-        method leaves the batch's Algorithm 1 step-1 iteration counts,
-        (B,) int32 on the device, in :attr:`last_proj_iters`."""
-        s = self.n_shards if shards is None else int(shards)
-        step_fn, _, sharding, state_sh = self._plane_for(s)
-        self._count("step", s, data.shape)
-        if sharding is not None:
-            # Host batches land directly in their sharded placement —
-            # one host->shard copy each, not a device-0 bounce plus an
-            # all-to-all reshard inside the jitted step. tau rides
-            # along replicated (k x d — bytes) so a buffer committed
-            # elsewhere by a refresh can never clash with the batch's
-            # device set when the active shard count switches.
-            tau, keys, data, point_mask, k_valid = (
-                jax.device_put(tau, state_sh),
-                jax.device_put(keys, sharding),
-                jax.device_put(data, sharding),
-                jax.device_put(point_mask, sharding),
-                jax.device_put(k_valid, sharding))
-        elif self.axes:
-            tau = jax.device_put(tau, self.mesh.devices.flatten()[0])
-        return self._keep_iters(step_fn(tau, keys, data, point_mask,
-                                        k_valid))
+    def _place(self, entry, params, batch):
+        """THE placement of one step's inputs. On the sharded plane the
+        host batch lands directly in its batch-axis sharding — one
+        host->shard copy each, not a device-0 bounce plus an all-to-all
+        reshard inside the jitted step — and the params ride along
+        replicated (tau is k x d — bytes), so a buffer committed
+        elsewhere by a refresh can never clash with the batch's device
+        set when the active shard count switches. A single-host step on
+        a mesh plane pins the params to the canonical device."""
+        _, _, batch_sh, param_sh = entry
+        if batch_sh is not None:
+            return (jax.device_put(params, param_sh),
+                    jax.device_put(batch, batch_sh))
+        if self.axes:
+            return (jax.device_put(params, self.mesh.devices.flatten()[0]),
+                    batch)
+        return params, batch
 
-    def _keep_iters(self, out):
-        """Split the compiled step's trailing iteration counts off into
-        :attr:`last_proj_iters`; return the rest."""
+    def step(self, tau, keys, data, point_mask, k_valid, shards=None, *,
+             heads=None, encoder=None, token_mask=None):
+        """Serve one fixed-shape batch through the plan's one step.
+        ``data`` is (B, n_pad, d) points, or with the encoder on
+        (B, n_pad, seq_pad, d) raw token/patch sequences with their
+        (B, n_pad, seq_pad) ``token_mask`` (DESIGN.md §17). Returns
+        (labels (B, n_pad), centers (B, k', d), center_mask (B, k'),
+        core weights (B, k')) and, with heads on (§16), also (preds
+        (B, d) f32, cluster (B,) i32, kept (B,) bool) — preds are zero
+        and kept False where a request overflowed its cluster's queue.
+        Sharded over the batch axis on the sharded plane, bitwise
+        identical per request at ANY active shard count (``shards``,
+        default: the full grant). The batch's Algorithm 1 step-1
+        iteration counts, (B,) int32 on the device, are left in
+        :attr:`last_proj_iters`."""
+        bad = [name for name, arg, on in (
+            ("encoder", encoder, self._has_encoder),
+            ("token_mask", token_mask, self._has_encoder),
+            ("heads", heads, self._has_heads)) if (arg is not None) != on]
+        if bad:
+            raise ServePlaneError(
+                f"step(): {bad} disagree with the plan (encoder="
+                f"{getattr(self.cfg, 'encoder', 'off')!r}, heads="
+                f"{getattr(self.cfg, 'heads', 'off')!r}): pass encoder "
+                f"params and token_mask exactly when the encoder is on, "
+                f"head params exactly when heads are on")
+        s = self.n_shards if shards is None else int(shards)
+        entry = self._plane_for(s)
+        self._count("step", s, data.shape)
+        params = ((tau, encoder, heads)
+                  if self._has_encoder or self._has_heads else tau)
+        batch = ((keys, data, point_mask, k_valid)
+                 + ((token_mask,) if self._has_encoder else ()))
+        params, batch = self._place(entry, params, batch)
+        out = entry[0](params, *batch)
         self.last_proj_iters = out[-1]
         return out[:-1]
 

@@ -743,32 +743,15 @@ class AttachService:
             if encoded:
                 self._encoded_points += sum(
                     item[1].shape[0] for item in batch)
-                if self._head_spec is not None:
-                    (labels, centers, cmask, weights, preds, cluster,
-                     kept) = self.plane.encoded_routed_step(
-                        self.tau, self.encoder, self.heads, keys,
-                        jnp.asarray(data), jnp.asarray(pmask),
-                        jnp.asarray(tmask), jnp.asarray(kv), shards=shards)
-                    routed = (preds, cluster, kept)
-                else:
-                    labels, centers, cmask, weights = self.plane.encode_step(
-                        self.tau, self.encoder, keys, jnp.asarray(data),
-                        jnp.asarray(pmask), jnp.asarray(tmask),
-                        jnp.asarray(kv), shards=shards)
-                    routed = ()
-            elif self._head_spec is not None:
-                (labels, centers, cmask, weights, preds, cluster,
-                 kept) = self.plane.routed_step(
-                    self.tau, self.heads, keys, jnp.asarray(data),
-                    jnp.asarray(pmask), jnp.asarray(kv), shards=shards)
-                routed = (preds, cluster, kept)
-            else:
-                labels, centers, cmask, weights = self.plane.step(
-                    self.tau, keys, jnp.asarray(data), jnp.asarray(pmask),
-                    jnp.asarray(kv), shards=shards)
-                routed = ()
+            out = self.plane.step(
+                self.tau, keys, jnp.asarray(data), jnp.asarray(pmask),
+                jnp.asarray(kv), shards=shards, heads=self.heads,
+                encoder=self.encoder,
+                token_mask=None if tmask is None else jnp.asarray(tmask))
+            labels, centers, cmask, weights = out[:4]
+            # With heads on, out[4:] is (preds, cluster, kept).
             entry = (batch, labels, version,
-                     self.plane.last_proj_iters) + routed
+                     self.plane.last_proj_iters) + tuple(out[4:])
         tel.stepped(B, n_pad)
         if cfg.fold_reports:
             self._fold(batch, rids, centers, cmask, weights,

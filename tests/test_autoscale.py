@@ -312,6 +312,11 @@ def test_plane_rejects_out_of_grant_shards(fixture_round):
     plane = sess.service.plane
     with pytest.raises(ServePlaneError, match="shards"):
         plane._plane_for(plane.n_shards + 1)
+    # Stage params whose presence disagrees with the plan (heads and
+    # encoder off here) are refused before anything is traced.
+    with pytest.raises(ServePlaneError, match="heads"):
+        plane.step(sess.service.tau, None, np.zeros((1, 8, D)), None,
+                   None, heads=sess.service.tau)
 
 
 def test_mixed_rung_flush_right_sizes_each_group(fixture_round):

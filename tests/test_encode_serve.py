@@ -18,6 +18,7 @@ import pytest
 import jax
 
 from repro.fed.api import FederationPlan, PlanError, Session
+from repro.fed.plane import ServePlaneError
 from repro.fed.stream import StreamConfigError
 from repro.utils.compat import make_mesh
 
@@ -118,6 +119,11 @@ def test_plan_validation_named_errors():
         _plan(encode_dtype="f16")
     with pytest.raises(PlanError, match="FederationPlan.encode_seq_len"):
         _plan(encode_seq_len=0)
+    # The plane's one step refuses a call that leaves out the encoder
+    # params and token mask of an encoder-on plan.
+    plane = Session.from_tau(_plan(), _tau()).service.plane
+    with pytest.raises(ServePlaneError, match="encoder"):
+        plane.step(_tau(), None, np.zeros((2, 16, SEQ, D)), None, None)
 
 
 # ------------------------------------------------ checkpoint schema --

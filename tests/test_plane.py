@@ -444,10 +444,30 @@ def test_encoder_off_plan_is_bitwise_inert(fixture_round, seed):
     np.testing.assert_array_equal(
         np.asarray(base.service._taubuf.bufs),
         np.asarray(off.service._taubuf.bufs))
-    assert off.service.plane._encode == {}
-    assert off.service.plane._enc_routed == {}
+    # The one entry cache holds only the plain step's entry: the plan's
+    # step is _make_step's own function, no encode stage composed in.
+    entries = off.service.plane._planes
+    assert list(entries) == [1]
+    assert (entries[1][0].__wrapped__.__qualname__
+            == "_make_step.<locals>.step")
     assert off.service.encoder is None
     assert off.service.stats()["encoder"]["mode"] == "off"
+
+
+def test_plain_step_lowers_to_jit_step_module():
+    """With heads and encoder off, the single-host entry is the plain
+    program: it lowers to the XLA module ``jit_step`` (the name the
+    benchmark's serve-step and fold/refresh device-trace metrics read),
+    with the plain five-argument signature and no wrapper."""
+    from repro.fed.plane import ServePlane
+    B, n = 4, 16
+    cfg = _plan(batch_size=B, bucket_sizes=(n,)).stream_config()
+    step = ServePlane(cfg)._plane_for(1)[0]
+    Sd = jax.ShapeDtypeStruct
+    text = step.lower(Sd((K, D), jnp.float32), Sd((B, 2), jnp.uint32),
+                      Sd((B, n, D), jnp.float32), Sd((B, n), jnp.bool_),
+                      Sd((B,), jnp.int32)).as_text()
+    assert "module @jit_step" in text
 
 
 def test_encoder_off_checkpoint_roundtrips_with_default_plan(
