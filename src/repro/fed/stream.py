@@ -125,6 +125,88 @@ _ENCODER_SALT = 0x454E434F  # "ENCO"
 # distinct compiled (n_pad, seq_pad) shapes to a static grid.
 _SEQ_RUNG_FLOOR = 8
 
+# Host staging arrays kept per batch shape: a batch is prepped into the
+# next slot of its shape's ring while the previous slot's step may still
+# be reading the other.
+STAGING_DEPTH = 2
+
+
+@jax.jit
+def _request_keys(base_key, rids):
+    """One PRNG key per request id, ``fold_in(base_key, rid)``: compiled
+    once per batch size, bitwise the keys of the eager vmap."""
+    return jax.vmap(jax.random.fold_in, in_axes=(None, 0))(base_key, rids)
+
+
+class _Staging:
+    """One reusable set of a batch's host inputs, for one batch shape.
+
+    Prep rewrites the arrays in place: each request's points go to the
+    front of its row and only the part of the row the previous batch
+    filled past them is cleared, so the arrays always equal a fresh
+    ``np.zeros`` fill. Rows past the batch repeat its last request
+    (pad-by-repeat). ``busy`` holds the device output of the step that
+    last read the arrays; :meth:`claim` waits on it before they are
+    written again (the device may read host memory asynchronously, or
+    alias it on the CPU backend)."""
+
+    def __init__(self, B: int, n_pad: int, s_pad: int, d: int):
+        self.data = np.zeros((B, n_pad, s_pad, d) if s_pad
+                             else (B, n_pad, d), np.float32)
+        self.tmask = np.zeros((B, n_pad, s_pad), bool) if s_pad else None
+        self.pmask = np.zeros((B, n_pad), bool)
+        self.kv = np.zeros((B,), np.int32)
+        self.rids = np.zeros((B,), np.int64)
+        # (points, tokens) written per row; None after an interrupted
+        # write, which makes the next write clear whole rows.
+        self.rows: Optional[List[Tuple[int, int]]] = [(0, 0)] * B
+        self.busy = None
+
+    def claim(self) -> bool:
+        """Wait until no dispatched step reads the arrays; True if that
+        meant blocking. A step that failed frees them all the same."""
+        busy, self.busy = self.busy, None
+        if busy is None:
+            return False
+        try:
+            waited = not all(x.is_ready() for x in jax.tree.leaves(busy))
+            jax.block_until_ready(busy)
+        except Exception:
+            return False
+        return waited
+
+    def write(self, batch) -> None:
+        """Fill the arrays with ``batch``, padded by repeating its last
+        request."""
+        B, n_pad = self.pmask.shape
+        s_pad = 0 if self.tmask is None else self.tmask.shape[2]
+        rows, self.rows = self.rows or [(n_pad, s_pad)] * B, None
+        data, tmask, pmask = self.data, self.tmask, self.pmask
+        last = len(batch) - 1
+        for i, (pn, ps) in enumerate(rows):
+            rid, arr, k_valid = batch[min(i, last)]
+            n = arr.shape[0]
+            if pn > n:
+                data[i, n:pn] = 0
+                pmask[i, n:pn] = False
+                if tmask is not None:
+                    tmask[i, n:pn] = False
+            if tmask is None:
+                s = 0
+                data[i, :n] = arr
+            else:
+                s = arr.shape[1]
+                if ps > s:
+                    data[i, :min(n, pn), s:ps] = 0
+                    tmask[i, :min(n, pn), s:ps] = False
+                data[i, :n, :s] = arr
+                tmask[i, :n, :s] = True
+            pmask[i, :n] = True
+            self.kv[i] = k_valid
+            self.rids[i] = rid
+            rows[i] = (n, s)
+        self.rows = rows
+
 
 class _ServerStateV3(NamedTuple):
     """Restore template for pre-v4 checkpoints: the fold state before
@@ -340,6 +422,9 @@ class AttachService:
         # Host spans and counters of the flush path (fed/telemetry.py):
         # observability only, never checkpointed.
         self.telemetry = FlushTelemetry()
+        # Host staging rings, one per batch shape served: never
+        # checkpointed (see _Staging).
+        self._staging: Dict[tuple, Tuple[List[_Staging], int]] = {}
         self._base_seed = int(seed)
         self._base_key = jax.random.PRNGKey(self._base_seed)
         self._next_id = int(next_id)
@@ -685,6 +770,25 @@ class AttachService:
         self._done.update(got)
         return mine
 
+    def _stage(self, batch, B: int, n_pad: int, s_pad: int) -> _Staging:
+        """Write ``batch`` into the next slot of the staging ring of its
+        shape, made (``STAGING_DEPTH`` slots) at the shape's first
+        batch; waits first if that slot's last step may still read it."""
+        tel = self.telemetry
+        key = (B, n_pad, s_pad)
+        ring, turn = self._staging.get(key, (None, 0))
+        if ring is None:
+            ring = [_Staging(B, n_pad, s_pad, self.cfg.d)
+                    for _ in range(STAGING_DEPTH)]
+            tel.staging_allocs += STAGING_DEPTH
+        else:
+            tel.staging_reuses += 1
+        self._staging[key] = (ring, (turn + 1) % STAGING_DEPTH)
+        st = ring[turn]
+        tel.staging_waits += st.claim()
+        st.write(batch)
+        return st
+
     def _serve_batch(self, batch, bucket, staged,
                      decision: AutoscaleDecision) -> None:
         """Phase 1 of a flush: dispatch one batch's serve step + fold
@@ -695,7 +799,9 @@ class AttachService:
         on, where the batch carries raw token sequences the plane
         encodes ahead of the solve). Nothing here waits on the device
         unless the admission policy needs report weights
-        (``needs_weight`` policies synchronize once per batch)."""
+        (``needs_weight`` policies synchronize once per batch) or the
+        staging slot the batch is written into is still being read by
+        the step of an earlier batch of its shape."""
         cfg = self.cfg
         encoded = self._enc_spec is not None
         n_pad, s_pad = bucket if encoded else (bucket, 0)
@@ -715,46 +821,28 @@ class AttachService:
             shards = shards_for(B, shards, self.autoscaler.n_axes)
         tel = self.telemetry
         with tel.phase("prep"):
-            if encoded:
-                data = np.zeros((B, n_pad, s_pad, cfg.d), np.float32)
-                tmask = np.zeros((B, n_pad, s_pad), bool)
-            else:
-                data = np.zeros((B, n_pad, cfg.d), np.float32)
-                tmask = None
-            pmask = np.zeros((B, n_pad), bool)
-            kv = np.full((B,), cfg.k_prime, np.int32)
-            rids = np.zeros((B,), np.int64)
-            for i in range(B):
-                rid, arr, k_valid = batch[min(i, len(batch) - 1)]  # pad=repeat
-                n = arr.shape[0]
-                if encoded:
-                    s = arr.shape[1]
-                    data[i, :n, :s] = arr
-                    tmask[i, :n, :s] = True
-                else:
-                    data[i, :n] = arr
-                pmask[i, :n] = True
-                kv[i] = k_valid
-                rids[i] = rid
-            keys = jax.vmap(lambda r: jax.random.fold_in(self._base_key, r))(
-                jnp.asarray(rids, jnp.uint32))
+            st = self._stage(batch, B, n_pad, s_pad)
+            keys = _request_keys(self._base_key, st.rids.astype(np.uint32))
             version = self._taubuf.version
         with tel.phase("step", rung=n_pad, rows=len(batch)):
             if encoded:
                 self._encoded_points += sum(
                     item[1].shape[0] for item in batch)
+            data, pmask, kv = (jnp.asarray(x)
+                               for x in (st.data, st.pmask, st.kv))
+            tmask = None if st.tmask is None else jnp.asarray(st.tmask)
+            st.busy = (data, pmask, kv, tmask)
             out = self.plane.step(
-                self.tau, keys, jnp.asarray(data), jnp.asarray(pmask),
-                jnp.asarray(kv), shards=shards, heads=self.heads,
-                encoder=self.encoder,
-                token_mask=None if tmask is None else jnp.asarray(tmask))
+                self.tau, keys, data, pmask, kv, shards=shards,
+                heads=self.heads, encoder=self.encoder, token_mask=tmask)
             labels, centers, cmask, weights = out[:4]
+            st.busy = labels
             # With heads on, out[4:] is (preds, cluster, kept).
             entry = (batch, labels, version,
                      self.plane.last_proj_iters) + tuple(out[4:])
         tel.stepped(B, n_pad)
         if cfg.fold_reports:
-            self._fold(batch, rids, centers, cmask, weights,
+            self._fold(batch, st.rids, centers, cmask, weights,
                        shards=shards)
         staged.append(entry)
 

@@ -14,8 +14,9 @@ event name before any ``#``):
 
 * ``kfed.flush`` — one ``AttachService`` flush, top level;
 * ``kfed.bucket`` — queue snapshot, autoscale decision and grouping;
-* ``kfed.prep`` — one per batch: host arrays, pad-by-repeat and the
-  request keys' ``fold_in`` derivation;
+* ``kfed.prep`` — one per batch: the batch written into its shape's
+  host staging arrays (pad-by-repeat), after any wait for the step
+  that last read them, and the request keys' ``fold_in`` derivation;
 * ``kfed.step`` — one per batch: the host-to-device transfers and the
   serve step's dispatch (carries the pad ``rung`` and the real ``rows``);
 * ``kfed.fold`` — fold admission and the fold scatter's dispatch;
@@ -36,7 +37,12 @@ projection took, ``proj_iters_max`` is the most any row took, and
 (``core.local_kmeans.PROJ_MAX_ITERS``) and so may stop unconverged.
 ``refresh_builds`` counts the refreshes during which Algorithm 2's
 program (``core.server.aggregate``) had to be traced anew: one per fold
-state shape, then none.
+state shape, then none. ``staging_allocs`` counts the host staging
+arrays made (a ring of ``fed.stream.STAGING_DEPTH`` per batch shape, at
+the shape's first batch), ``staging_reuses`` the batches prepped into a
+ring made before them (every batch past each shape's first), and
+``staging_waits`` those reuses that blocked on the step still reading
+the slot.
 
 They are observability only: no scaling or refresh decision reads them
 (wall clock does not replay, DESIGN.md §12), no checkpoint carries them,
@@ -71,6 +77,9 @@ class FlushTelemetry:
         self.proj_iters = 0
         self.proj_iters_max = 0
         self.proj_capped = 0
+        self.staging_allocs = 0
+        self.staging_reuses = 0
+        self.staging_waits = 0
         self.seconds: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
         self._inner: List[float] = []   # nested seconds per open phase
 
@@ -113,4 +122,7 @@ class FlushTelemetry:
                 "proj_iters": self.proj_iters,
                 "proj_iters_max": self.proj_iters_max,
                 "proj_capped": self.proj_capped,
+                "staging_allocs": self.staging_allocs,
+                "staging_reuses": self.staging_reuses,
+                "staging_waits": self.staging_waits,
                 **{f"{p}_s": s for p, s in self.seconds.items()}}

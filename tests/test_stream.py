@@ -285,3 +285,180 @@ def test_fold_drops_out_of_range_ids():
                                   np.ones((2, 3)))
     np.testing.assert_array_equal(np.asarray(st.received),
                                   [False, False, False, True])
+
+
+# ------------------------------------------------ host staging rings --
+
+def _fresh_fill(batch, B, n_pad, s_pad, d, k_prime):
+    """A batch's host inputs built from scratch: ``np.zeros``, each
+    request copied to the front of its row, rows past the batch
+    repeating its last request."""
+    data = np.zeros((B, n_pad, s_pad, d) if s_pad else (B, n_pad, d),
+                    np.float32)
+    tmask = np.zeros((B, n_pad, s_pad), bool) if s_pad else None
+    pmask = np.zeros((B, n_pad), bool)
+    kv = np.full((B,), k_prime, np.int32)
+    rids = np.zeros((B,), np.int64)
+    for i in range(B):
+        rid, arr, k_valid = batch[min(i, len(batch) - 1)]
+        n = arr.shape[0]
+        if s_pad:
+            data[i, :n, :arr.shape[1]] = arr
+            tmask[i, :n, :arr.shape[1]] = True
+        else:
+            data[i, :n] = arr
+        pmask[i, :n] = True
+        kv[i] = k_valid
+        rids[i] = rid
+    return data, tmask, pmask, kv, rids
+
+
+def _spy_staging(svc, monkeypatch):
+    """Record, after every prep, the batch and a copy of its staging
+    arrays."""
+    seen, orig = [], svc._stage
+
+    def spy(batch, B, n_pad, s_pad):
+        st = orig(batch, B, n_pad, s_pad)
+        seen.append((list(batch), B, n_pad, s_pad,
+                     [None if x is None else x.copy() for x in (
+                         st.data, st.tmask, st.pmask, st.kv, st.rids)]))
+        return st
+
+    monkeypatch.setattr(svc, "_stage", spy)
+    return seen
+
+
+@pytest.mark.parametrize("encoded", [False, True])
+def test_staging_fill_is_bitwise_a_fresh_fill(fixture_round, encoded,
+                                              monkeypatch):
+    """Six batches of one shape, so each of the two ring slots is
+    written long -> short -> long (points and, encoded, tokens), some
+    batches short of rows and padded by repeat: after every prep the
+    reused arrays equal a fresh ``np.zeros`` fill byte for byte."""
+    rng = np.random.default_rng(31)
+    if encoded:
+        d, kp, seq = 16, 3, 16
+        sess = Session.from_tau(FederationPlan(
+            k=8, k_prime=kp, d=d, capacity=128, batch_size=4,
+            bucket_sizes=(16, 32), encoder="qwen1.5-0.5b",
+            encode_seq_len=seq), rng.normal(size=(8, d)).astype(np.float32))
+        # (n, seq) extents inside the one (32, 16) rung.
+        long_, short = ((30, 32), (15, 16)), ((17, 19), (9, 11))
+    else:
+        _, rr = fixture_round
+        d, kp = D, KP
+        sess = _session(rr)
+        long_, short = ((58, 64),), ((33, 36),)
+    seen = _spy_staging(sess.service, monkeypatch)
+
+    def request(ext):
+        shape = [int(rng.integers(lo, hi + 1)) for lo, hi in ext]
+        return rng.normal(size=shape + [d]).astype(np.float32)
+
+    for ext, count in ((long_, 4), (long_, 3), (short, 1), (short, 2),
+                       (long_, 3), (long_, 4)):
+        sess.serve([request(ext) for _ in range(count)],
+                   [int(rng.integers(1, kp + 1)) for _ in range(count)])
+    assert len(seen) == 6
+    assert len({(B, n_pad, s_pad) for _, B, n_pad, s_pad, _ in seen}) == 1
+    for batch, B, n_pad, s_pad, got in seen:
+        want = _fresh_fill(batch, B, n_pad, s_pad, d, kp)
+        for g, w in zip(got, want):
+            if w is None:
+                assert g is None
+                continue
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert g.tobytes() == w.tobytes()
+    f = sess.stats()["flush"]
+    assert (f["staging_allocs"], f["staging_reuses"]) == (2, 5)
+
+
+class _Running:
+    """A step's labels as a device still running would hold them: not
+    ready until something blocks on them."""
+
+    def __init__(self, labels):
+        self.labels, self.done = labels, False
+
+    def is_ready(self):
+        return self.done
+
+    def block_until_ready(self):
+        self.done = True
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.labels, dtype)
+
+
+def test_ring_wrap_within_a_flush_keeps_labels(fixture_round,
+                                               monkeypatch):
+    """One flush of four same-shape batches reuses each ring slot
+    before any label reaches the host. Each step's labels stay "running"
+    until waited on: no slot is written while the step that read it
+    runs, each reuse waits once, and every request gets the labels it
+    gets served alone."""
+    from repro.fed import stream
+    fm, rr = fixture_round
+    reqs, _, kvs = _requests(fm, 8, seed=41, n_lo=33, n_hi=64)
+    sess = _session(rr, batch_size=2)
+    plane, running, last = sess.service.plane, [], {}
+    step, write = plane.step, stream._Staging.write
+
+    def lazy_step(*a, **kw):
+        out = step(*a, **kw)
+        running.append(_Running(out[0]))
+        last[last.pop("writing")] = running[-1]
+        return (running[-1],) + tuple(out[1:])
+
+    def checked_write(st, batch):
+        assert last.get(id(st)) is None or last[id(st)].done
+        last["writing"] = id(st)
+        write(st, batch)
+
+    monkeypatch.setattr(plane, "step", lazy_step)
+    monkeypatch.setattr(stream._Staging, "write", checked_write)
+    got = sess.serve(reqs, kvs)
+    f = sess.stats()["flush"]
+    assert (f["batches"], f["staging_allocs"], f["staging_reuses"],
+            f["staging_waits"]) == (4, 2, 3, 2)
+    assert [r.done for r in running] == [True, True, False, False]
+    monkeypatch.undo()
+    alone = _session(rr, batch_size=2)
+    for r, kv, lbl in zip(reqs, kvs, got):
+        np.testing.assert_array_equal(alone.serve([r], [kv])[0], lbl)
+
+
+def test_failed_batch_releases_its_staging_slot(fixture_round,
+                                                monkeypatch):
+    """A step that fails after its batch was staged requeues the batch
+    and leaves the ring usable: the retry serves every request the
+    labels of a clean session, through the same two slots."""
+    fm, rr = fixture_round
+    reqs, _, kvs = _requests(fm, 3, seed=23, n_lo=10, n_hi=20)
+    clean = _session(rr, batch_size=1).serve(reqs, kvs)
+    sess = _session(rr, batch_size=1)
+    for r, kv in zip(reqs, kvs):
+        sess.submit(r, kv)
+    plane, calls = sess.service.plane, []
+    orig = plane.step
+
+    def boom(*a, **kw):
+        calls.append(1)
+        if len(calls) == 2:
+            raise RuntimeError("boom")
+        return orig(*a, **kw)
+
+    monkeypatch.setattr(plane, "step", boom)
+    with pytest.raises(RuntimeError):
+        sess.flush()
+    st = sess.stats()
+    assert st["pending"] == 2 and st["undelivered"] == 1
+    monkeypatch.setattr(plane, "step", orig)
+    got = sess.flush()
+    assert len(got) == 3
+    for rid, want in zip(sorted(got), clean):
+        np.testing.assert_array_equal(got[rid], want)
+    f = sess.stats()["flush"]
+    assert (f["staging_allocs"], f["staging_reuses"]) == (2, 3)

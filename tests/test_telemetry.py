@@ -166,3 +166,39 @@ def test_refresh_builds_algorithm_2_once(fixture_round, drift, capacity):
     f = sess.stats()["flush"]
     assert (f["refreshes"], f["refresh_builds"]) == (4, 1)
     assert compiled == []
+
+
+def test_staging_rings_stop_allocating_after_warm_up(fixture_round):
+    """The first flush makes one ring per batch shape (two shapes here,
+    of ``STAGING_DEPTH`` slots each); later flushes of the same mix
+    allocate nothing and prep every batch into a retained slot."""
+    from repro.fed.stream import STAGING_DEPTH
+    fm, rr = fixture_round
+    sess = _session(rr)
+    reqs, kvs = _requests(fm)
+    sess.serve(reqs, kvs)
+    f0 = sess.stats()["flush"]
+    assert (f0["staging_allocs"], f0["staging_reuses"]) == (
+        2 * STAGING_DEPTH, 0)
+    for _ in range(3):
+        sess.serve(reqs, kvs)
+    f1 = sess.stats()["flush"]
+    assert f1["staging_allocs"] == f0["staging_allocs"]
+    assert f1["staging_reuses"] == f1["batches"] - f0["batches"] == 6
+    assert 0 <= f1["staging_waits"] <= f1["staging_reuses"]
+
+
+def test_request_keys_match_the_eager_fold_in():
+    """The compiled key derivation gives bitwise the keys of the eager
+    ``vmap(fold_in)`` over the ids cast to uint32, wrap-around
+    included."""
+    import jax.numpy as jnp
+    from repro.fed.stream import _request_keys
+    base = jax.random.PRNGKey(20260)
+    rids = np.array([0, 1, 7, 4095, 2 ** 31 - 1, 2 ** 31 + 5,
+                     2 ** 32 - 1, 2 ** 32 + 3], np.int64)
+    eager = jax.vmap(lambda r: jax.random.fold_in(base, r))(
+        jnp.asarray(rids, jnp.uint32))
+    got = _request_keys(base, rids.astype(np.uint32))
+    assert got.dtype == eager.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(eager))
